@@ -385,25 +385,18 @@ def _recover_child(workdir: str, n: int) -> None:
     time.sleep(600)          # parent kills us long before this expires
 
 
-def run_recover_gate(smoke: bool = False) -> Dict:
-    """Kill-and-replay: SIGKILL a service with admitted-but-unbatched
-    requests, recover over the same workdir, and demand zero losses.
+def _reference_labels(workload, prefix: str) -> Dict[str, "np.ndarray"]:
+    """Labels per content hash from an uninterrupted in-process run.
 
-    A child process admits N requests (durable in the WAL, never batched)
-    and is killed with SIGKILL — no cleanup, no atexit, the admission
-    queue dies in memory.  A fresh service over the same workdir runs
-    ``recover()``: every request must come back through replay, complete,
-    and produce labels identical to an uninterrupted reference run.
+    Gates call this only after every child process that needs the device
+    has exited: a chip belongs to one process at a time, and this one
+    holds it from here on.
     """
     import numpy as np
 
     from repro.service import ClusteringService, MiningClient, content_key
 
-    n = 4 if smoke else 8
-    workload = _build_gate_workload(n)
-
-    # uninterrupted reference run (separate workdir)
-    refdir = tempfile.mkdtemp(prefix="svc_recover_ref_")
+    refdir = tempfile.mkdtemp(prefix=prefix)
     ref_labels: Dict[str, "np.ndarray"] = {}
     try:
         service = ClusteringService(refdir, max_batch=4, max_wait_s=0.005)
@@ -420,6 +413,25 @@ def run_recover_gate(smoke: bool = False) -> Dict:
                     h.result(300)["labels"])
     finally:
         shutil.rmtree(refdir, ignore_errors=True)
+    return ref_labels
+
+
+def run_recover_gate(smoke: bool = False) -> Dict:
+    """Kill-and-replay: SIGKILL a service with admitted-but-unbatched
+    requests, recover over the same workdir, and demand zero losses.
+
+    A child process admits N requests (durable in the WAL, never batched)
+    and is killed with SIGKILL — no cleanup, no atexit, the admission
+    queue dies in memory.  A fresh service over the same workdir runs
+    ``recover()``: every request must come back through replay, complete,
+    and produce labels identical to an uninterrupted reference run.
+    """
+    import numpy as np
+
+    from repro.service import ClusteringService, MiningClient
+
+    n = 4 if smoke else 8
+    workload = _build_gate_workload(n)
 
     # crash run: child admits, parent SIGKILLs
     workdir = tempfile.mkdtemp(prefix="svc_recover_gate_")
@@ -445,6 +457,10 @@ def run_recover_gate(smoke: bool = False) -> Dict:
             if proc.poll() is None:
                 proc.send_signal(signal.SIGKILL)
             proc.wait(30)
+
+        # this process starts JAX only now that the child, which needed
+        # the device, is gone: the uninterrupted reference run first
+        ref_labels = _reference_labels(workload, "svc_recover_ref_")
 
         # recovery run over the dead process's workdir.  Losses are
         # counted per workload item (did every expected content hash
@@ -598,12 +614,7 @@ def run_fleet_gate(smoke: bool = False) -> Dict:
 
     import numpy as np
 
-    from repro.service import (
-        ClusteringService,
-        MiningClient,
-        content_key,
-        exposition_errors,
-    )
+    from repro.service import content_key, exposition_errors
     from repro.service.fleet import FleetRouter, WorkerManager
     from repro.service.wal import RequestLog
 
@@ -621,22 +632,6 @@ def run_fleet_gate(smoke: bool = False) -> Dict:
     datasets = [make_data(i) for i in range(n_victim + n_live)]
     all_params = [{"k": 3, "seed": 500 + i, "max_iters": 50}
                   for i in range(n_victim + n_live)]
-
-    # uninterrupted single-process reference: labels per content hash
-    refdir = tempfile.mkdtemp(prefix="svc_fleet_ref_")
-    ref_labels: Dict[str, "np.ndarray"] = {}
-    try:
-        service = ClusteringService(refdir, max_batch=4, max_wait_s=0.005)
-        client = MiningClient(service=service)
-        with service:
-            handles = [client.submit("ref", "kmeans", d, params=p,
-                                     executor="jax-ref")
-                       for d, p in zip(datasets, all_params)]
-            for d, p, h in zip(datasets, all_params, handles):
-                ref_labels[content_key("kmeans", p, d)] = (
-                    h.result(300)["labels"])
-    finally:
-        shutil.rmtree(refdir, ignore_errors=True)
 
     root = tempfile.mkdtemp(prefix="svc_fleet_gate_")
     manager = WorkerManager(
@@ -694,14 +689,6 @@ def run_fleet_gate(smoke: bool = False) -> Dict:
                 print(f"# victim-admitted request {h.tenant} failed: "
                       f"{e!r}", file=sys.stderr)
 
-        lost = mismatched = 0
-        for key, ref in ref_labels.items():
-            got = produced.get(key)
-            if got is None:
-                lost += 1
-            elif not (got == ref).all():
-                mismatched += 1
-
         takeover = manager.takeovers[0] if manager.takeovers else {}
         replayed = int(takeover.get("replayed", 0))
         if replayed < max(1, admitted_at_victim):
@@ -737,22 +724,35 @@ def run_fleet_gate(smoke: bool = False) -> Dict:
         ):
             if needle not in text:
                 problems.append(f"missing fleet series: {needle}")
-        return {
-            "admitted": n_victim + n_live,
-            "admitted_at_victim": admitted_at_victim,
-            "replayed": replayed,
-            "adopter": takeover.get("adopter"),
-            "lost": lost,
-            "mismatched": mismatched,
-            "victim_wal_pending": victim_pending,
-            "replaced": replaced,
-            "problems": problems,
-        }
     finally:
         exporter.stop()
         router.close()
         manager.stop()
         shutil.rmtree(root, ignore_errors=True)
+
+    # the workers, which needed the device, are gone: now this process
+    # may start JAX for the uninterrupted single-process reference
+    ref_labels = _reference_labels(
+        [("ref", "kmeans", d, p) for d, p in zip(datasets, all_params)],
+        "svc_fleet_ref_")
+    lost = mismatched = 0
+    for key, ref in ref_labels.items():
+        got = produced.get(key)
+        if got is None:
+            lost += 1
+        elif not (got == ref).all():
+            mismatched += 1
+    return {
+        "admitted": n_victim + n_live,
+        "admitted_at_victim": admitted_at_victim,
+        "replayed": replayed,
+        "adopter": takeover.get("adopter"),
+        "lost": lost,
+        "mismatched": mismatched,
+        "victim_wal_pending": victim_pending,
+        "replaced": replaced,
+        "problems": problems,
+    }
 
 
 # -- continuous-batching speed gate -------------------------------------------
@@ -1161,18 +1161,15 @@ def run_standby_gate(smoke: bool = False) -> Dict:
     respawns every worker one at a time; all handles must resolve with
     reference-identical labels, every worker pid must change, and a
     fleet-wide ``/reload`` must converge on one epoch on every worker.
+
+    This process starts JAX (promotion, reference run) only after the
+    child and the fleet workers, which need the device, have exited.
     """
     import urllib.request
 
     import numpy as np
 
-    from repro.service import (
-        ClusteringService,
-        MiningClient,
-        StandbyReplica,
-        content_key,
-        exposition_errors,
-    )
+    from repro.service import StandbyReplica, content_key, exposition_errors
     from repro.service.fleet import FleetRouter, WorkerManager
     from repro.service.telemetry import render_prometheus
 
@@ -1180,26 +1177,7 @@ def run_standby_gate(smoke: bool = False) -> Dict:
     workload = _build_gate_workload(n)
     problems: List[str] = []
 
-    # uninterrupted reference run (separate workdir): labels per hash
-    refdir = tempfile.mkdtemp(prefix="svc_standby_ref_")
-    ref_labels: Dict[str, "np.ndarray"] = {}
-    try:
-        service = ClusteringService(refdir, max_batch=4, max_wait_s=0.005)
-        client = MiningClient(service=service)
-        with service:
-            handles = [
-                client.submit(tenant, algo, data, params=params,
-                              executor="jax-ref")
-                for tenant, algo, data, params in workload
-            ]
-            for (tenant, algo, data, params), h in zip(workload, handles):
-                ref_labels[content_key(algo, params,
-                                       np.asarray(data, np.float32))] = (
-                    h.result(300)["labels"])
-    finally:
-        shutil.rmtree(refdir, ignore_errors=True)
-
-    # -- phase 1: ship under live load, SIGKILL, promote -----------------------
+    # -- phase 1a: ship under live load, SIGKILL -----------------------------
     primary_dir = tempfile.mkdtemp(prefix="svc_standby_primary_")
     standby_dir = tempfile.mkdtemp(prefix="svc_standby_mirror_")
     standby = StandbyReplica(standby_dir).start()
@@ -1254,6 +1232,75 @@ def run_standby_gate(smoke: bool = False) -> Dict:
             if needle not in replica_text:
                 problems.append(f"missing replica series: {needle}")
 
+        # -- phase 2: fleet rolling restart under durable load ---------------
+        root = tempfile.mkdtemp(prefix="svc_standby_fleet_")
+        manager = WorkerManager(
+            root, 2, worker_config={"max_batch": 4, "max_wait_s": 0.05},
+            heartbeat_interval=0.25)
+        manager.start()
+        router = FleetRouter(manager)
+        rolled: Dict[str, "np.ndarray"] = {}
+        roll_failed = 0
+        restarted_pids = {}
+        reload_epochs = {}
+        try:
+            # fleet-wide live reload first: every worker must land on
+            # epoch 1
+            reload_result = router.reload({"tenant_rate": 77.0})
+            reload_epochs = reload_result["epochs"]
+            if not reload_result["converged"]:
+                problems.append(
+                    f"fleet reload did not converge: epochs "
+                    f"{reload_result['epochs']}, errors "
+                    f"{reload_result['errors']}")
+            elif set(reload_result["epochs"].values()) != {1}:
+                problems.append(
+                    f"stale config epoch after fleet reload: "
+                    f"{reload_result['epochs']}")
+
+            before_pids = {name: spec.proc.pid
+                           for name, spec in manager.workers.items()}
+            handles = []
+            for tenant, algo, data, params in workload:
+                h = router.submit(tenant, algo, data, params=params,
+                                  executor="jax-ref", durable=True)
+                h.admitted(60)
+                handles.append(h)
+
+            manager.rolling_restart(drain_timeout=60.0)
+
+            for (tenant, algo, data, params), h in zip(workload, handles):
+                key = content_key(algo, params,
+                                  np.asarray(data, np.float32))
+                try:
+                    rolled[key] = h.result(300)["labels"]
+                except Exception as e:
+                    print(f"# rolled request {tenant} failed: {e!r}",
+                          file=sys.stderr)
+                    roll_failed += 1
+
+            restarted_pids = {name: spec.proc.pid
+                              for name, spec in manager.workers.items()}
+            stuck = [name for name, pid in restarted_pids.items()
+                     if before_pids.get(name) == pid]
+            if stuck:
+                problems.append(f"rolling restart left old pids: {stuck}")
+            if len(manager.restarts) != len(before_pids):
+                problems.append(
+                    f"expected {len(before_pids)} restart records, got "
+                    f"{len(manager.restarts)}")
+            # the upgraded fleet still serves
+            tenant, algo, data, params = workload[0]
+            post = router.submit(tenant, algo, data,
+                                 params=dict(params, seed=9999),
+                                 executor="jax-ref")
+            post.result(300)
+        finally:
+            router.close()
+            manager.stop()
+            shutil.rmtree(root, ignore_errors=True)
+
+        # -- phase 1b: promote the standby (JAX starts in this process) ------
         svc, summary = standby.promote(max_batch=4, max_wait_s=0.005)
         produced: Dict[str, "np.ndarray"] = {}
         try:
@@ -1279,13 +1326,6 @@ def run_standby_gate(smoke: bool = False) -> Dict:
                     "lacks repro_config_epoch 1")
         finally:
             svc.stop(drain=True)
-        lost = mismatched = 0
-        for ck, ref in ref_labels.items():
-            got = produced.get(ck)
-            if got is None:
-                lost += 1
-            elif not (got == ref).all():
-                mismatched += 1
         if promoted_pending:
             problems.append(f"promoted WAL still has {promoted_pending} "
                             f"pending admits")
@@ -1294,73 +1334,18 @@ def run_standby_gate(smoke: bool = False) -> Dict:
         shutil.rmtree(primary_dir, ignore_errors=True)
         shutil.rmtree(standby_dir, ignore_errors=True)
 
-    # -- phase 2: fleet rolling restart under durable load ---------------------
-    root = tempfile.mkdtemp(prefix="svc_standby_fleet_")
-    manager = WorkerManager(
-        root, 2, worker_config={"max_batch": 4, "max_wait_s": 0.05},
-        heartbeat_interval=0.25)
-    manager.start()
-    router = FleetRouter(manager)
-    roll_lost = roll_mismatched = 0
-    restarted_pids = {}
-    reload_epochs = {}
-    try:
-        # fleet-wide live reload first: every worker must land on epoch 1
-        reload_result = router.reload({"tenant_rate": 77.0})
-        reload_epochs = reload_result["epochs"]
-        if not reload_result["converged"]:
-            problems.append(
-                f"fleet reload did not converge: epochs "
-                f"{reload_result['epochs']}, errors "
-                f"{reload_result['errors']}")
-        elif set(reload_result["epochs"].values()) != {1}:
-            problems.append(
-                f"stale config epoch after fleet reload: "
-                f"{reload_result['epochs']}")
-
-        before_pids = {name: spec.proc.pid
-                       for name, spec in manager.workers.items()}
-        handles = []
-        for i, (tenant, algo, data, params) in enumerate(workload):
-            h = router.submit(tenant, algo, data, params=params,
-                              executor="jax-ref", durable=True)
-            h.admitted(60)
-            handles.append(h)
-
-        manager.rolling_restart(drain_timeout=60.0)
-
-        for (tenant, algo, data, params), h in zip(workload, handles):
-            key = content_key(algo, params, np.asarray(data, np.float32))
-            try:
-                got = h.result(300)["labels"]
-            except Exception as e:
-                print(f"# rolled request {tenant} failed: {e!r}",
-                      file=sys.stderr)
-                roll_lost += 1
-                continue
-            if not (got == ref_labels[key]).all():
-                roll_mismatched += 1
-
-        restarted_pids = {name: spec.proc.pid
-                          for name, spec in manager.workers.items()}
-        stuck = [name for name, pid in restarted_pids.items()
-                 if before_pids.get(name) == pid]
-        if stuck:
-            problems.append(f"rolling restart left old pids: {stuck}")
-        if len(manager.restarts) != len(before_pids):
-            problems.append(
-                f"expected {len(before_pids)} restart records, got "
-                f"{len(manager.restarts)}")
-        # the upgraded fleet still serves
-        tenant, algo, data, params = workload[0]
-        post = router.submit(tenant, algo, data,
-                             params=dict(params, seed=9999),
-                             executor="jax-ref")
-        post.result(300)
-    finally:
-        router.close()
-        manager.stop()
-        shutil.rmtree(root, ignore_errors=True)
+    ref_labels = _reference_labels(workload, "svc_standby_ref_")
+    lost = mismatched = 0
+    roll_mismatched = 0
+    for ck, ref in ref_labels.items():
+        got = produced.get(ck)
+        if got is None:
+            lost += 1
+        elif not (got == ref).all():
+            mismatched += 1
+        got = rolled.get(ck)
+        if got is not None and not (got == ref).all():
+            roll_mismatched += 1
 
     return {
         "admitted": n,
@@ -1371,7 +1356,7 @@ def run_standby_gate(smoke: bool = False) -> Dict:
         "promoted_pending": promoted_pending,
         "reload_epochs": reload_epochs,
         "rolled": len(workload),
-        "roll_lost": roll_lost,
+        "roll_lost": roll_failed,
         "roll_mismatched": roll_mismatched,
         "restarts": len(restarted_pids),
         "problems": problems,

@@ -38,7 +38,6 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.runtime.compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.cancellation import CancellationToken
@@ -135,21 +134,14 @@ def make_sharded_masked_kmeans_step(mesh: Mesh, cfg: KMeansConfig):
 # Strategy 2: ring systolic (shard_map + ppermute)
 # ---------------------------------------------------------------------------
 
-def _pvary(x, axis: str):
-    """Mark a constant as device-varying over `axis` (shard_map VMA typing)."""
-    from repro.runtime.compat import pvary
-
-    return pvary(x, axis)
-
-
 def _ring_body(x_rows, x_cols0, combine, init, axis: str):
     """Rotate column shards around the ring, folding tiles into `init`."""
-    from repro.runtime.compat import axis_size
-
-    p = axis_size(axis)
+    p = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     perm = [(i, (i + 1) % p) for i in range(p)]
-    init = jax.tree.map(lambda a: _pvary(a, axis), init)
+    # constants are device-varying over the ring axis (shard_map typing)
+    init = jax.tree.map(
+        lambda a: jax.lax.pcast(a, (axis,), to="varying"), init)
 
     def body(step, carry):
         acc, x_cols = carry
@@ -166,7 +158,7 @@ def _ring_body(x_rows, x_cols0, combine, init, axis: str):
 def _tile_adj(xi, xj, eps2):
     xi = xi.astype(jnp.float32)
     xj = xj.astype(jnp.float32)
-    cross = xi @ xj.T
+    cross = jnp.matmul(xi, xj.T, precision=jax.lax.Precision.HIGHEST)
     d2 = (
         jnp.sum(xi * xi, 1)[:, None]
         - 2.0 * cross
@@ -190,7 +182,7 @@ def make_ring_degree(mesh: Mesh, eps: float, axis: str = "data"):
         init = jnp.zeros((x_shard.shape[0],), jnp.int32)
         return _ring_body(x_shard, x_shard, combine, init, axis)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=P(axis, None), out_specs=P(axis)
     ))
 
@@ -209,7 +201,7 @@ def make_ring_expand(mesh: Mesh, eps: float, axis: str = "data"):
         init = jnp.zeros((x_shard.shape[0],), bool)
         return _ring_body(x_shard, (x_shard, f_shard), combine, init, axis)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis)),
@@ -433,7 +425,8 @@ def clustering_step_for_dryrun(cfg: KMeansConfig):
         xf = x.astype(jnp.float32)
         cf = c.astype(jnp.float32)
         cross = jnp.einsum("nd,kd->nk", xf, cf,
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32,
+                           precision=jax.lax.Precision.HIGHEST)
         cross = lshard(cross, "points", "centroids")
         cnorm = jnp.sum(cf * cf, axis=1)
         score = cnorm[None, :] - 2.0 * cross          # argmin-equivalent
@@ -443,7 +436,8 @@ def clustering_step_for_dryrun(cfg: KMeansConfig):
 
         onehot = jax.nn.one_hot(assign, cfg.k, dtype=jnp.float32)
         onehot = lshard(onehot, "points", "centroids")
-        sums = jnp.einsum("nk,nd->kd", onehot, xf)
+        sums = jnp.einsum("nk,nd->kd", onehot, xf,
+                          precision=jax.lax.Precision.HIGHEST)
         counts = jnp.sum(onehot, axis=0)
         has = counts > 0
         c_new = jnp.where(has[:, None],

@@ -82,7 +82,8 @@ def _assign(x, c, cfg: KMeansConfig):
 def _update_centroids(x, assign, k: int, c_old):
     """One-hot matmul centroid update (MXU-friendly; GSPMD-reducible)."""
     onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32)      # (n, k)
-    sums = jnp.einsum("nk,nd->kd", onehot, x.astype(jnp.float32))
+    sums = jnp.einsum("nk,nd->kd", onehot, x.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
     counts = jnp.sum(onehot, axis=0)                            # (k,)
     has_pts = counts > 0
     safe = jnp.where(has_pts, counts, 1.0)[:, None]
@@ -117,7 +118,8 @@ def masked_kmeans_step(x, c, mask, cfg: KMeansConfig):
     assign, d2 = _assign(x, c, cfg)
     w = mask.astype(jnp.float32)
     onehot = jax.nn.one_hot(assign, cfg.k, dtype=jnp.float32) * w[:, None]
-    sums = jnp.einsum("nk,nd->kd", onehot, x.astype(jnp.float32))
+    sums = jnp.einsum("nk,nd->kd", onehot, x.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
     counts = jnp.sum(onehot, axis=0)
     has_pts = counts > 0
     safe = jnp.where(has_pts, counts, 1.0)[:, None]
@@ -327,7 +329,8 @@ def _minibatch_update(c, counts, xb, cfg: KMeansConfig):
     assign, d2 = _assign(xb, c, cfg)
     onehot = jax.nn.one_hot(assign, cfg.k, dtype=jnp.float32)
     bcounts = jnp.sum(onehot, axis=0)
-    bsums = jnp.einsum("nk,nd->kd", onehot, xb.astype(jnp.float32))
+    bsums = jnp.einsum("nk,nd->kd", onehot, xb.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
     counts_new = counts + bcounts
     lr = jnp.where(bcounts > 0, bcounts / jnp.maximum(counts_new, 1.0), 0.0)
     bmean = bsums / jnp.maximum(bcounts, 1.0)[:, None]

@@ -32,8 +32,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
@@ -111,20 +111,11 @@ def flash_attention_kernel(
     scale = 1.0 / math.sqrt(d)
     grid = (bh, sq // block_q, sk // block_k)
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-
-        scratch = [
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ]
-    except ImportError:  # pure-interpret fallback
-        scratch = [
-            pl.MemoryRef((block_q, 1), jnp.float32),  # pragma: no cover
-            pl.MemoryRef((block_q, 1), jnp.float32),
-            pl.MemoryRef((block_q, d), jnp.float32),
-        ]
+    scratch = [
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, d), jnp.float32),
+    ]
 
     return pl.pallas_call(
         functools.partial(
@@ -142,6 +133,6 @@ def flash_attention_kernel(
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        **tpu_compiler_params(("parallel", "parallel", "arbitrary"),
-                              interpret=interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(kv_len.reshape(1, 1), q, k, v)

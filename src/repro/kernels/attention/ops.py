@@ -13,10 +13,7 @@ from repro.kernels.attention.attention import (
     DEFAULT_BLOCK_Q,
     flash_attention_kernel,
 )
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.runtime.backend import pallas_interpret
 
 
 def _round_up(x: int, m: int) -> int:
@@ -38,7 +35,7 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Returns (B, Sq, H, D).  H % KV == 0 (GQA: kv repeated)."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     b, sq, h, d = q.shape
     _, sk, kv, _ = k.shape
     assert h % kv == 0

@@ -33,8 +33,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 DEFAULT_BLOCK_N = 512   # points per tile
 DEFAULT_BLOCK_K = 128   # centroids per tile
@@ -67,6 +67,7 @@ def _assign_kernel(x_ref, c_ref, val_ref, idx_ref, *, block_k: int):
         x, c,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     cnorm = jnp.sum(c * c, axis=1)  # (bk,)
     # score = ||c||^2 - 2 x·c  (+||x||^2 omitted: constant per row)
@@ -127,5 +128,6 @@ def assign_clusters_kernel(
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
         ],
         interpret=interpret,
-        **tpu_compiler_params(("parallel", "arbitrary"), interpret=interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(x, c)

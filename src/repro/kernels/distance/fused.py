@@ -33,10 +33,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 from repro.kernels.distance.distance import DEFAULT_BLOCK_N, _BIG
-from repro.kernels.distance.ops import _default_interpret, _round_up
+from repro.kernels.distance.ops import _round_up
+from repro.runtime.backend import pallas_interpret
 
 
 def _fused_step_kernel(x_ref, c_ref, w_ref, idx_ref, sums_ref, cnt_ref,
@@ -68,6 +69,7 @@ def _fused_step_kernel(x_ref, c_ref, w_ref, idx_ref, sums_ref, cnt_ref,
         x, c,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     cnorm = jnp.sum(c * c, axis=1)                # (kp,)
     # score = ||c||^2 - 2 x·c; ||x||^2 is argmin-neutral and re-added for
@@ -89,6 +91,7 @@ def _fused_step_kernel(x_ref, c_ref, w_ref, idx_ref, sums_ref, cnt_ref,
         onehot, x,
         dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )                                             # (kp, d)
     cnt_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).reshape(
         cnt_ref.shape)
@@ -143,7 +146,8 @@ def fused_step_kernel(
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
-        **tpu_compiler_params(("arbitrary",), interpret=interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
     )(x, c, w)
 
 
@@ -167,7 +171,7 @@ def fused_masked_assign_update(
       masked inertia f32 ()).
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     n, d = x.shape
     k, _ = c.shape
 

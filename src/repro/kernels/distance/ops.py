@@ -24,10 +24,7 @@ from repro.kernels.distance.distance import (
     assign_clusters_kernel,
 )
 from repro.kernels.distance.ref import pairwise_sq_dists_ref
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.runtime.backend import pallas_interpret
 
 
 def _round_up(x: int, m: int) -> int:
@@ -57,7 +54,7 @@ def assign_clusters(
       (distance minus ||x||^2) — cheaper, argmin-equivalent.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     n, d = x.shape
     k, _ = c.shape
 

@@ -29,8 +29,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 DEFAULT_BLOCK_I = 512
 DEFAULT_BLOCK_J = 512
@@ -44,6 +44,7 @@ def _tile_d2(xi, xj):
         xi, xj,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     ni = jnp.sum(xi * xi, axis=1)  # (bi,)
     nj = jnp.sum(xj * xj, axis=1)  # (bj,)
@@ -103,7 +104,8 @@ def degree_kernel(
         out_specs=pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         interpret=interpret,
-        **tpu_compiler_params(("parallel", "arbitrary"), interpret=interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(eps2.reshape(1, 1), x, x)
 
 
@@ -134,5 +136,6 @@ def expand_kernel(
         out_specs=pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
-        **tpu_compiler_params(("parallel", "arbitrary"), interpret=interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(eps2.reshape(1, 1), x, x, frontier)

@@ -21,12 +21,9 @@ from repro.kernels.neighbor.neighbor import (
     degree_kernel,
     expand_kernel,
 )
+from repro.runtime.backend import pallas_interpret
 
 _PAD_COORD = 1e10
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -54,7 +51,7 @@ def epsilon_degree(
 ) -> jnp.ndarray:
     """|N_eps(p)| for every point (self included), int32 (n,)."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     n, _ = x.shape
     bi = block_i or min(DEFAULT_BLOCK_I, _round_up(n, 8))
     bj = block_j or min(DEFAULT_BLOCK_J, _round_up(n, 8))
@@ -77,7 +74,7 @@ def expand_frontier(
 ) -> jnp.ndarray:
     """Bool (n,): within eps of some frontier point (the expansion kernel)."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = pallas_interpret()
     n, _ = x.shape
     bi = block_i or min(DEFAULT_BLOCK_I, _round_up(n, 8))
     bj = block_j or min(DEFAULT_BLOCK_J, _round_up(n, 8))

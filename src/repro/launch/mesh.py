@@ -11,7 +11,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -19,16 +19,16 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     (pod, data, model) — 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
                    axes: Tuple[str, ...] = ("data", "model")) -> Mesh:
     """Small mesh for subprocess tests (8 host devices)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> Mesh:
     """Whatever devices exist, as a 1D data mesh (CPU smoke runs)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), (AxisType.Auto,))

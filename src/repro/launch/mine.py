@@ -101,6 +101,7 @@ def main() -> None:
     ap.add_argument("--workdir", default="/tmp/repro_mine")
     ap.add_argument("--no-kernel", action="store_true")
     args = ap.parse_args()
+    backend_mod.enable_compile_cache()
     out = run_mining_job(
         algo=args.algo, features=args.features, clusters=args.clusters,
         size=args.size, workdir=args.workdir, use_kernel=not args.no_kernel,

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
 
 import jax
@@ -45,6 +46,7 @@ from repro.service import (
     EnergyBudgetExceeded,
     JobSuspended,
     MiningClient,
+    RequestDropped,
     TelemetryServer,
     chrome_trace,
 )
@@ -109,10 +111,15 @@ def submit_with_backoff(client: MiningClient, tenant, algo, data, *,
 def drive(client: MiningClient, workload, rate: float,
           executor: str | None, timeout: float = 300.0,
           ttl: float | None = None) -> dict:
-    """Submit at the offered rate; wait for every handle; count failures."""
+    """Submit at the offered rate; wait for every handle; count failures.
+
+    Suspended, dropped (shutdown or deadline) and shed requests are the
+    service keeping its contract; anything else a handle raises counts
+    under ``errors``.
+    """
     handles = []
     gap = 1.0 / rate if rate > 0 else 0.0
-    failures = {"suspended": 0, "dropped": 0, "rejected": 0}
+    failures = {"suspended": 0, "dropped": 0, "rejected": 0, "errors": 0}
     t0 = time.time()
     for i, (tenant, algo, data, params) in enumerate(workload):
         target = t0 + i * gap
@@ -125,13 +132,16 @@ def drive(client: MiningClient, workload, rate: float,
             failures["rejected"] += 1
         else:
             handles.append(h)
-    for h in handles:
+    for i, h in enumerate(handles):
         try:
             h.result(timeout)
         except JobSuspended:
             failures["suspended"] += 1
-        except Exception:            # RequestDropped, deadline expiry, ...
+        except RequestDropped:
             failures["dropped"] += 1
+        except Exception as e:
+            print(f"request {i} failed: {e!r}", file=sys.stderr)
+            failures["errors"] += 1
     return failures
 
 
@@ -252,11 +262,15 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def run_fleet(args) -> None:
+def run_fleet(args) -> int:
     """--fleet N: the same workload through N worker processes behind the
-    consistent-hash router, then the fleet scorecard."""
+    consistent-hash router, then the fleet scorecard.  Returns the number
+    of requests that ended in an error."""
     from repro.service.fleet import FleetRouter, WorkerManager
 
+    # the workers own the chips: this process builds the workload on the
+    # CPU so that it never holds a device a worker needs
+    jax.config.update("jax_platforms", "cpu")
     worker_config = {
         "max_batch": args.max_batch,
         "max_wait_s": args.max_wait_ms / 1000.0,
@@ -310,6 +324,7 @@ def run_fleet(args) -> None:
                                     points=args.points, seed=1)
             post = drive(router, verify, args.rate, executor, ttl=args.ttl)
             print(f"# rolling restart: post-restart batch failures {post}")
+            failures["errors"] += post["errors"]
         snap = router.metrics_snapshot()
         fleet = snap["fleet"]
         print(json.dumps(fleet, indent=2, default=str))
@@ -322,6 +337,7 @@ def run_fleet(args) -> None:
               f"{fleet['router']['retries']} retries / "
               f"{fleet['router']['spills']} bounded-load spills, "
               f"failures {failures}")
+        return failures["errors"]
     finally:
         if exporter is not None:
             exporter.stop()
@@ -329,9 +345,12 @@ def run_fleet(args) -> None:
         manager.stop()
 
 
-def main() -> None:
+def main() -> int:
+    """Exit status: 1 when any request ended in an error, else 0 (a run
+    suspended by SIGTERM is a clean exit)."""
     parser = build_parser()
     args = parser.parse_args()
+    backend_mod.enable_compile_cache()
     if args.standby and args.fleet:
         parser.error("--standby is single-process mode only: each fleet "
                      "worker needs its own standby (see "
@@ -340,8 +359,7 @@ def main() -> None:
         parser.error("--rolling-restart needs --fleet N (the in-process "
                      "equivalent is ClusteringService.handover())")
     if args.fleet:
-        run_fleet(args)
-        return
+        return 1 if run_fleet(args) else 0
 
     backend_mod.load()
     warm_start = (json.loads(args.warm_start)
@@ -391,6 +409,7 @@ def main() -> None:
     executor = None if args.executor == "auto" else args.executor
     # SIGTERM/SIGINT -> cooperative preemption: in-flight batches
     # checkpoint and park SUSPENDED (finish later with --resume)
+    replay_errors = 0
     with PreemptionGuard(service.token), service:
         if args.recover:
             # resume suspended batches, then replay every admitted request
@@ -408,6 +427,7 @@ def main() -> None:
                     h.result(300)
                 except Exception as e:
                     print(f"replayed request {h.request_id} failed: {e!r}")
+                    replay_errors += 1
         if args.reload:
             cfg = service.apply_config(json.loads(args.reload))
             print(f"# reload: epoch {cfg.epoch} applied")
@@ -463,7 +483,8 @@ def main() -> None:
           f"error rate {slo['observed_error_rate']:.3f} vs "
           f"{slo['error_rate_target']:.3f} "
           f"(burn {slo['errors_burn_rate']:.2f})")
+    return 1 if failures["errors"] or replay_errors else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

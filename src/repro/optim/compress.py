@@ -20,7 +20,6 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.runtime.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -77,7 +76,7 @@ def compressed_psum_int8(
 
     flat, treedef = jax.tree_util.tree_flatten(grads)
     in_specs = (tuple(P() for _ in flat), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs=tuple(P() for _ in flat),

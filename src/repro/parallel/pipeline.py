@@ -27,7 +27,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from repro.runtime.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -81,7 +80,7 @@ def pipeline_apply(
         )
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P()),

@@ -19,10 +19,15 @@ exists to prevent (calling an OpenCL symbol before the library is loaded).
 from __future__ import annotations
 
 import dataclasses
-import threading
+import glob
+import os
+import pathlib
 from typing import Any, List, Optional
 
 from repro.runtime.locks import RWLock
+
+# src/repro/runtime/backend.py -> the checkout root
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
 
 
 class BackendNotLoadedError(RuntimeError):
@@ -33,37 +38,126 @@ class BackendNotLoadedError(RuntimeError):
     """
 
 
+class UnknownChipError(RuntimeError):
+    """The devices JAX reports are not a chip this repository knows.
+
+    Raised instead of guessing peaks or a memory budget for hardware whose
+    numbers nobody recorded.
+    """
+
+
+class SilentCpuFallbackError(RuntimeError):
+    """TPU chips are attached, yet JAX came up on the CPU.
+
+    JAX skips a TPU backend that fails to start and carries on on the CPU;
+    the Pallas kernels would then run in interpret mode without a word.
+    """
+
+
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
-    """Peak-rate card for one accelerator chip (roofline constants)."""
+    """Peak-rate card for one accelerator chip (roofline constants).
+
+    Peaks are None where no published number exists (the CPU backend).
+    """
 
     name: str
-    peak_bf16_flops: float  # FLOP/s
-    hbm_bandwidth: float    # byte/s
-    ici_link_bandwidth: float  # byte/s per link
-    hbm_bytes: int
-    vmem_bytes: int
+    hbm_bytes: int          # device memory; the dispatch budget's basis
+    peak_bf16_flops: Optional[float] = None  # FLOP/s
+    hbm_bandwidth: Optional[float] = None    # byte/s
+    ici_link_bandwidth: Optional[float] = None  # byte/s per link
+    vmem_bytes: Optional[int] = None
 
 
-# TPU v5e: the compile target for every kernel and dry-run in this repo.
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over 4 links.
 TPU_V5E = ChipSpec(
     name="tpu_v5e",
+    hbm_bytes=16 * 1024**3,
     peak_bf16_flops=197e12,
     hbm_bandwidth=819e9,
     ici_link_bandwidth=50e9,
-    hbm_bytes=16 * 1024**3,
     vmem_bytes=128 * 1024**2,
 )
 
-# The host we actually run on (correctness/interpret mode only).
-HOST_CPU = ChipSpec(
-    name="host_cpu",
-    peak_bf16_flops=1e11,
-    hbm_bandwidth=1e10,
-    ici_link_bandwidth=1e9,
-    hbm_bytes=32 * 1024**3,
-    vmem_bytes=32 * 1024**2,
-)
+# Keyed by ``device_kind`` exactly as JAX reports it on the chip.
+CHIPS_BY_KIND = {"TPU v5 lite": TPU_V5E}
+
+# The CPU backend (tests, interpret mode): host memory bounds a request,
+# and it has no peaks to measure against.
+HOST_CPU = ChipSpec(name="host_cpu", hbm_bytes=32 * 1024**3)
+
+
+def chip_spec(device: Any) -> ChipSpec:
+    """The spec of one JAX device; an unrecorded accelerator raises."""
+    if device.platform == "cpu":
+        return HOST_CPU
+    try:
+        return CHIPS_BY_KIND[device.device_kind]
+    except KeyError:
+        raise UnknownChipError(
+            f"no ChipSpec for {device.platform} device_kind "
+            f"{device.device_kind!r}; known kinds: {sorted(CHIPS_BY_KIND)}"
+        ) from None
+
+
+def host_tpu_chips() -> int:
+    """TPU chips this process can open, counted without starting JAX.
+
+    Counts the chips' device nodes: ``/dev/accel<N>``, or ``/dev/vfio/<N>``
+    where the chips are bound to VFIO.  PCI is not counted: it lists every
+    chip of the host, also those a container was not given.  0 where
+    ``JAX_PLATFORMS`` holds JAX off the TPU: running on the CPU is then a
+    choice, not a fallback.
+    """
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return len(glob.glob("/dev/accel[0-9]*")
+               or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _refuse_silent_cpu(platform: str) -> None:
+    if platform == "cpu" and host_tpu_chips() > 0:
+        raise SilentCpuFallbackError(
+            "this host has TPU chips but JAX runs on the CPU: the TPU "
+            "backend failed to start (another process may hold the chip). "
+            "Set JAX_PLATFORMS=cpu to run on the CPU on purpose.")
+
+
+def pallas_interpret() -> bool:
+    """Whether Pallas TPU kernels run in interpret mode: on the CPU only.
+
+    On the TPU they compile to Mosaic.  Any other platform, or a CPU
+    backend that stands in for a TPU that failed to start, raises.
+    """
+    import jax
+
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform != "cpu":
+        raise UnknownChipError(
+            f"the Pallas kernels target the TPU; JAX runs on {platform!r}")
+    _refuse_silent_cpu(platform)
+    return True
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Called by entry points only, never at import time: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it and nothing
+    is changed; otherwise the cache goes to ``<repo>/.jax_cache``.  The
+    path is part of each entry's key, so it must not move between runs.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @dataclasses.dataclass
@@ -96,7 +190,8 @@ class _BackendRegistry:
 
                 devices = jax.devices()
                 platform = devices[0].platform
-                chip = TPU_V5E if platform == "tpu" else HOST_CPU
+                _refuse_silent_cpu(platform)
+                chip = chip_spec(devices[0])
                 self._backend = Backend(
                     platform=platform,
                     device_count=len(devices),

@@ -16,19 +16,14 @@ This module makes the executable an explicit, service-lifetime object:
   snapshot, and the ``--speed-gate`` asserts zero misses after warm-up
   (the cache is *actually* persistent, not re-compiling per batch).
 
-AOT compilation can be version- or backend-fragile; a failing lower()
-falls back to the plain jitted callable (same signature, jax's own cache
-underneath) so the serving path never depends on AOT support.
+A program the compiler refuses raises here, and so fails the batch that
+asked for it: serving never swaps in another program in its place.
 """
 
 from __future__ import annotations
 
-import functools
-import logging
 import threading
 from typing import Any, Callable, Dict, Optional, Tuple
-
-logger = logging.getLogger(__name__)
 
 
 class ExecutableCache:
@@ -40,7 +35,6 @@ class ExecutableCache:
         self.hits = 0
         self.misses = 0
         self.warmed = 0
-        self.aot_failures = 0
 
     # -- keys ----------------------------------------------------------------
 
@@ -99,15 +93,7 @@ class ExecutableCache:
         x_aval = jax.ShapeDtypeStruct((int(n_pad), int(d)), jnp.float32)
         c_aval = jax.ShapeDtypeStruct((int(cfg.k), int(d)), jnp.float32)
         m_aval = jax.ShapeDtypeStruct((int(n_pad),), jnp.bool_)
-        try:
-            return step.lower(x_aval, c_aval, m_aval, cfg=cfg).compile()
-        except Exception:
-            with self._lock:
-                self.aot_failures += 1
-            logger.exception(
-                "AOT compile failed for kmeans step (n_pad=%d, d=%d); "
-                "falling back to the jitted callable", n_pad, d)
-            return functools.partial(step, cfg=cfg)
+        return step.lower(x_aval, c_aval, m_aval, cfg=cfg).compile()
 
     # -- stats ---------------------------------------------------------------
 
@@ -118,7 +104,6 @@ class ExecutableCache:
                 "hits": self.hits,
                 "misses": self.misses,
                 "warmed": self.warmed,
-                "aot_failures": self.aot_failures,
             }
 
 

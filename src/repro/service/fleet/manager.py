@@ -24,6 +24,10 @@ The manager owns the fleet's *lifecycle* half (the router owns routing):
 Death and takeover are announced to subscribers (``on_death``) so the
 router can drop the victim from the hash ring and re-pin sticky tenants
 to the adopter before the takeover replay even lands.
+
+A TPU chip belongs to one process at a time.  On a host with TPU chips
+each worker is given exactly one chip of its own, and a fleet with more
+workers than chips is refused.  The manager itself never starts JAX.
 """
 
 from __future__ import annotations
@@ -32,12 +36,14 @@ import json
 import logging
 import os
 import signal
+import socket
 import subprocess
 import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from repro.runtime import backend as backend_mod
 from repro.service.fleet import rpc
 from repro.service.wal import WalLocked
 
@@ -104,6 +110,14 @@ class WorkerManager:
                  standbys: Optional[Dict[str, str]] = None) -> None:
         if n_workers < 1:
             raise ValueError("a fleet needs at least one worker")
+        self.tpu_chips = backend_mod.host_tpu_chips()
+        if self.tpu_chips and n_workers > self.tpu_chips:
+            raise ValueError(
+                f"a fleet of {n_workers} workers needs {n_workers} TPU "
+                f"chips, one per worker (a chip belongs to one process at "
+                f"a time), and this host has {self.tpu_chips}: run at most "
+                f"{self.tpu_chips} workers, or set JAX_PLATFORMS=cpu to run "
+                f"the fleet on the CPU")
         self.root = root
         self.n_workers = int(n_workers)
         self.worker_config = dict(worker_config or {})
@@ -158,6 +172,27 @@ class WorkerManager:
 
     # -- spawn ---------------------------------------------------------------
 
+    def chip_env(self, name: str) -> Dict[str, str]:
+        """Environment that gives worker ``name`` (``worker-<i>``) chip i.
+
+        Empty without TPU chips, and where one worker takes the host's only
+        chip.  A per-process chip bound lets every worker load libtpu at
+        once; each needs its own port for libtpu's process server.
+        """
+        if self.tpu_chips <= 1:
+            return {}
+        index = int(name.rsplit("-", 1)[1])
+        with socket.socket() as sock:
+            sock.bind(("127.0.0.1", 0))
+            port = sock.getsockname()[1]
+        return {
+            "TPU_VISIBLE_CHIPS": str(index),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        }
+
     def _spawn(self, name: str) -> WorkerSpec:
         spec = WorkerSpec(name, os.path.join(self.root, name))
         os.makedirs(spec.workdir, exist_ok=True)
@@ -169,6 +204,7 @@ class WorkerManager:
         cfg = dict(self.worker_config)
         cfg.update(self.overrides.get(name, {}))
         env = dict(os.environ)
+        env.update(self.chip_env(name))
         env["PYTHONPATH"] = _src_pythonpath()
         env.pop("REPRO_FAULT", None)
         env.pop("REPRO_FAULT_LEDGER", None)

@@ -47,6 +47,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional
 from urllib.parse import parse_qs, urlparse
 
+from repro.runtime import backend as backend_mod
 from repro.service.fleet import rpc
 from repro.service.service import ClusteringService
 from repro.service.session import StreamingSession
@@ -383,6 +384,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[list] = None) -> int:
     args = build_parser().parse_args(argv)
+    backend_mod.enable_compile_cache()
     cfg = json.loads(args.config)
     service = ClusteringService(args.workdir, **cfg).start()
     # A rolling-restart successor inherits its predecessor's workdir; any

@@ -249,7 +249,8 @@ from repro.core.kmeans import KMeansConfig, kmeans_step
 from repro.kernels.neighbor.ref import epsilon_degree_ref, expand_frontier_ref
 from repro.data.synthetic import ClusterSpec, make_blobs
 
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                     (jax.sharding.AxisType.Auto,) * 2)
 x, _, _ = make_blobs(jax.random.PRNGKey(0), ClusterSpec(2, 4, 128))
 cfg = KMeansConfig(k=4, use_kernel=False)
 c0 = x[:4].astype(jnp.float32)

@@ -553,3 +553,27 @@ def test_fleet_rolling_restart_and_live_reload(tmp_path):
     finally:
         router.close()
         manager.stop()
+
+
+def test_fleet_refuses_more_workers_than_tpu_chips(tmp_path, monkeypatch):
+    """A chip belongs to one process: a fleet larger than the host's chip
+    count is refused up front, never started to hang or fall to the CPU."""
+    from repro.runtime import backend as backend_mod
+
+    monkeypatch.setattr(backend_mod, "host_tpu_chips", lambda: 2)
+    with pytest.raises(ValueError, match="one per worker"):
+        WorkerManager(str(tmp_path), 3)
+
+
+def test_fleet_gives_each_worker_its_own_chip(tmp_path, monkeypatch):
+    from repro.runtime import backend as backend_mod
+
+    monkeypatch.setattr(backend_mod, "host_tpu_chips", lambda: 4)
+    manager = WorkerManager(str(tmp_path), 4)
+    envs = [manager.chip_env(f"worker-{i}") for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    # no chips (JAX_PLATFORMS=cpu, or a CPU host): workers get no binding
+    monkeypatch.setattr(backend_mod, "host_tpu_chips", lambda: 0)
+    assert WorkerManager(str(tmp_path), 4).chip_env("worker-1") == {}

@@ -182,7 +182,8 @@ from jax.sharding import PartitionSpec as P
 from repro.parallel.sharding import DEFAULT_RULES, spec_for_shape
 from repro.parallel.resolve import spec_for_decl
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     (jax.sharding.AxisType.Auto,) * 2)
 # divisible: heads 8 on model=4
 s = spec_for_shape(DEFAULT_RULES, ('embed', 'heads', 'head_dim'), mesh,
                    (64, 8, 16))
@@ -208,7 +209,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.parallel.pipeline import pipeline_apply, split_stages
 
-mesh = jax.make_mesh((4,), ('pipe',))
+mesh = jax.make_mesh((4,), ('pipe',),
+                     (jax.sharding.AxisType.Auto,))
 L, D, M, MB = 8, 16, 6, 4
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (L, D, D), jnp.float32) * 0.3
@@ -255,7 +257,8 @@ import sys; sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
 from repro.optim.compress import compressed_psum_int8
 
-mesh = jax.make_mesh((8,), ('data',))
+mesh = jax.make_mesh((8,), ('data',),
+                     (jax.sharding.AxisType.Auto,))
 grads = {{'w': jnp.linspace(-1, 1, 256, dtype=jnp.float32)}}
 out = compressed_psum_int8(mesh, grads, jax.random.PRNGKey(0), ('data',))
 # mean over 8 identical replicas == the input, up to int8 quantization

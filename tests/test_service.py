@@ -25,6 +25,7 @@ from repro.service import (
     ClusteringService,
     JobSuspended,
     MicroBatcher,
+    MiningClient,
     MiningRequest,
     ResultCache,
     content_key,
@@ -514,3 +515,22 @@ def test_sigkill_subprocess_then_resume(tmp_path):
     outcomes = ex.resume_suspended()
     assert len(outcomes) == 1 and not outcomes[0].suspended
     assert (outcomes[0].results[0]["labels"] == oracle).all()
+
+
+def test_compile_failure_fails_the_request(tmp_path, monkeypatch):
+    """A step program the compiler refuses fails the requests of its batch;
+    no other program is swapped in to serve them."""
+    from repro.service.exec_cache import ExecutableCache
+
+    def refuse(self, n_pad, d, cfg):
+        raise RuntimeError("compiler refused the K-Means step")
+
+    monkeypatch.setattr(ExecutableCache, "_compile_kmeans", refuse)
+    with ClusteringService(str(tmp_path), max_batch=1,
+                           max_wait_s=0.0) as svc:
+        h = MiningClient(service=svc).submit(
+            "t", "kmeans", blob(3), params={"k": 4, "seed": 1},
+            executor=EXECUTOR_JAX_REF)
+        with pytest.raises(Exception, match="compiler refused"):
+            h.result(120)
+        assert "aot_failures" not in svc.exec_cache.stats()
