@@ -1,0 +1,11 @@
+"""Makes the benchmark's modules and the program importable; the tests run
+on the CPU, with JAX's compilation cache off."""
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for path in (BENCH, os.path.join(ROOT, "src")):
+    if path not in sys.path:
+        sys.path.insert(0, path)
