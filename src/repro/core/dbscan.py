@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cancellation import CancellationToken
+from repro.core.meter import StepMeter
 from repro.kernels.neighbor.ops import epsilon_degree, expand_frontier
 from repro.kernels.neighbor.ref import epsilon_degree_ref, expand_frontier_ref
 
@@ -211,6 +212,10 @@ def fit(x: jnp.ndarray, cfg: DBSCANConfig) -> DBSCANResult:
 # --- host-driven, cancellable + resumable solver ----------------------------
 
 
+def _host_any(a) -> bool:
+    return bool(a.any())
+
+
 @dataclasses.dataclass
 class DBSCANRunState:
     """Preemption snapshot of a host-driven run.
@@ -253,22 +258,29 @@ def fit_resumable(
     state: Optional[DBSCANRunState] = None,
     valid_mask: Optional[jnp.ndarray] = None,
     on_progress: Optional[Callable[[int, int], None]] = None,
-    on_state: Optional[Callable[[DBSCANRunState], None]] = None,
+    on_state: Optional[Callable[[Callable[[], DBSCANRunState]], None]] = None,
     state_interval: int = 8,
+    meter: Optional[StepMeter] = None,
 ) -> Tuple[DBSCANResult, Optional[DBSCANRunState]]:
     """Host loop; the abort flag is polled between kernel executions, exactly
     as in the paper.  State is carried in the paper's packed int16 word.
 
     ``state`` resumes a previously interrupted run mid-BFS; on cancellation
     the returned second element is the snapshot to resume from (``None`` on
-    normal completion).  ``on_state`` is invoked with a snapshot every
-    ``state_interval`` expansions — the service's periodic-checkpoint hook.
+    normal completion).  ``on_state`` is invoked every ``state_interval``
+    expansions — the service's periodic-checkpoint hook — with a function
+    that reads the snapshot back from the device when called (during the
+    hook), so the caller's checkpoint covers the read-back that feeds it.
     ``valid_mask`` marks real rows in a padded array: masked-out rows can
     never be core points (with min_pts=1 an isolated pad row would
-    otherwise seed a phantom singleton cluster).
+    otherwise seed a phantom singleton cluster).  ``meter`` counts the step
+    programs (the degree pass and each expansion) and every blocking
+    device-to-host read the loop makes.
     """
+    meter = meter if meter is not None else StepMeter()
     n = x.shape[0]
     deg = _degree_step(x, cfg)       # kernel launch 1 (main loop kernel)
+    meter.steps += 1
     core = deg >= cfg.min_pts
     if valid_mask is not None:
         core = core & valid_mask
@@ -298,13 +310,18 @@ def fit_resumable(
             nexp=nexp,
         )
 
+    def _read_snapshot() -> DBSCANRunState:
+        # three reads: pack_state's cluster-id bound, the word, the frontier
+        return meter.read(_snapshot, reads=3)
+
     while True:
         # inner: expand the in-flight cluster's frontier to exhaustion
-        while bool(frontier.any()):
+        while meter.read(_host_any, frontier):
             if _poll():
                 cancelled = True
                 break
             reached = _expand_step(x, frontier, cfg)  # expansion kernel launch
+            meter.steps += 1
             nexp += 1
             new = reached & (labels == 0)
             labels = jnp.where(new, cid, labels)
@@ -314,14 +331,14 @@ def fit_resumable(
             if on_progress is not None:
                 on_progress(cid, nexp)
             if on_state is not None and nexp % state_interval == 0:
-                on_state(_snapshot())
+                on_state(_read_snapshot)
         if cancelled:
             break
         if _poll():
             cancelled = True
             break
         # outer: seed the next cluster at the lowest-index unvisited core pt
-        todo = np.asarray(core & ~visited)
+        todo = meter.read(np.asarray, core & ~visited)
         if not todo.any():
             break
         cid += 1
@@ -332,7 +349,8 @@ def fit_resumable(
             )
         frontier = jnp.zeros((n,), bool).at[int(np.argmax(todo))].set(True)
 
-    packed = pack_state(labels, visited, member, core)
+    # pack_state reads the largest cluster id back to bound it
+    packed = meter.read(pack_state, labels, visited, member, core)
     result = DBSCANResult(
         labels=finish(packed),
         core_mask=core,
@@ -340,7 +358,7 @@ def fit_resumable(
         expansions=jnp.int32(nexp),
         cancelled=cancelled,
     )
-    return result, (_snapshot() if cancelled else None)
+    return result, (_read_snapshot() if cancelled else None)
 
 
 def fit_cancellable(

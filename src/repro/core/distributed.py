@@ -56,6 +56,7 @@ from repro.core.kmeans import (
     kmeans_step,
     masked_kmeans_step,
 )
+from repro.core.meter import StepMeter
 from repro.kernels.distance.ref import assign_clusters_ref
 from repro.kernels.neighbor.ref import _sq_dists  # noqa: F401 (docs)
 
@@ -244,8 +245,10 @@ def sharded_kmeans_fit_resumable(
     *,
     centroids: np.ndarray,
     start_iteration: int = 0,
-    on_state: Optional[Callable[[Dict[str, np.ndarray]], None]] = None,
+    on_state: Optional[
+        Callable[[Callable[[], Dict[str, np.ndarray]]], None]] = None,
     state_interval: int = 8,
+    meter: Optional[StepMeter] = None,
 ) -> Tuple[KMeansResult, Optional[Dict[str, np.ndarray]]]:
     """Masked Lloyd host loop with points/mask sharded over the mesh.
 
@@ -253,7 +256,11 @@ def sharded_kmeans_fit_resumable(
     caller pads; see ``shard_rows``).  Returns ``(result, mid_state)`` where
     ``mid_state`` is the resume snapshot on cancellation (None otherwise),
     in the same tree form the single-device paradigm checkpoints.
+    ``on_state`` gets a function that reads that snapshot back when called
+    (as :func:`repro.core.dbscan.fit_resumable`'s does); ``meter`` counts
+    the Lloyd steps and the blocking reads.
     """
+    meter = meter if meter is not None else StepMeter()
     daxes = data_axes(mesh)
     step = make_sharded_masked_kmeans_step(mesh, cfg)
     xs = jax.device_put(jnp.asarray(x_pad, jnp.float32),
@@ -267,19 +274,24 @@ def sharded_kmeans_fit_resumable(
     stepped = False
     converged = False
     cancelled = False
+
+    def _mid() -> Dict[str, np.ndarray]:
+        return {
+            "centroids": meter.read(np.asarray, c, np.float32),
+            "iteration": np.int32(it),
+        }
+
     while it < cfg.max_iters:
         if token is not None and token.cancelled():
             cancelled = True
             break
         assign, c, shift, inertia = step(xs, c, ms)
+        meter.steps += 1
         stepped = True
         it += 1
         if on_state is not None and it % state_interval == 0:
-            on_state({
-                "centroids": np.asarray(c, np.float32),
-                "iteration": np.int32(it),
-            })
-        if float(shift) < cfg.tol:
+            on_state(_mid)
+        if meter.read(float, shift) < cfg.tol:
             converged = True
             break
     if not stepped and not cancelled:
@@ -288,6 +300,7 @@ def sharded_kmeans_fit_resumable(
         # of the *incoming* centroids (computed before the update), which
         # we keep — without it the result would be all-zero labels.
         assign, _, _, inertia = step(xs, c, ms)
+        meter.steps += 1
     result = KMeansResult(
         centroids=c,
         labels=jnp.asarray(assign).astype(jnp.int16),
@@ -296,13 +309,7 @@ def sharded_kmeans_fit_resumable(
         converged=jnp.asarray(converged),
         cancelled=cancelled,
     )
-    mid = None
-    if cancelled:
-        mid = {
-            "centroids": np.asarray(c, np.float32),
-            "iteration": np.int32(it),
-        }
-    return result, mid
+    return result, (_mid() if cancelled else None)
 
 
 def sharded_dbscan_fit_resumable(
@@ -313,9 +320,10 @@ def sharded_dbscan_fit_resumable(
     *,
     state: Optional[DBSCANRunState] = None,
     valid_mask: Optional[np.ndarray] = None,
-    on_state: Optional[Callable[[DBSCANRunState], None]] = None,
+    on_state: Optional[Callable[[Callable[[], DBSCANRunState]], None]] = None,
     state_interval: int = 8,
     axis: str = "data",
+    meter: Optional[StepMeter] = None,
 ) -> Tuple[DBSCANResult, Optional[DBSCANRunState]]:
     """DBSCAN host loop with the two O(n^2) kernels ring-sharded.
 
@@ -325,6 +333,7 @@ def sharded_dbscan_fit_resumable(
     checkpointable and mesh-shape independent.  Same contract as
     :func:`repro.core.dbscan.fit_resumable`.
     """
+    meter = meter if meter is not None else StepMeter()
     n = x_pad.shape[0]
     degree_fn = make_ring_degree(mesh, float(cfg.eps), axis)
     expand_fn = make_ring_expand(mesh, float(cfg.eps), axis)
@@ -332,7 +341,8 @@ def sharded_dbscan_fit_resumable(
     f_sharding = NamedSharding(mesh, P(axis))
     xs = jax.device_put(jnp.asarray(x_pad, jnp.float32), x_sharding)
 
-    deg = np.asarray(degree_fn(xs))          # ring launch 1 (degree kernel)
+    deg = meter.read(np.asarray, degree_fn(xs))   # ring launch 1 (degree)
+    meter.steps += 1
     core = deg >= cfg.min_pts
     if valid_mask is not None:
         core = core & np.asarray(valid_mask)
@@ -363,13 +373,18 @@ def sharded_dbscan_fit_resumable(
             nexp=nexp,
         )
 
+    def _read_snapshot() -> DBSCANRunState:
+        # pack_state packs on the device: its cluster-id bound and the word
+        return meter.read(_snapshot, reads=2)
+
     while True:
         while bool(frontier.any()):
             if _poll():
                 cancelled = True
                 break
             fs = jax.device_put(jnp.asarray(frontier), f_sharding)
-            reached = np.asarray(expand_fn(xs, fs))   # ring expansion launch
+            reached = meter.read(np.asarray, expand_fn(xs, fs))  # ring launch
+            meter.steps += 1
             nexp += 1
             new = reached & (labels == 0)
             labels = np.where(new, cid, labels)
@@ -377,7 +392,7 @@ def sharded_dbscan_fit_resumable(
             member = member | new
             frontier = new & core
             if on_state is not None and nexp % state_interval == 0:
-                on_state(_snapshot())
+                on_state(_read_snapshot)
         if cancelled or _poll():
             cancelled = True
             break
@@ -393,7 +408,7 @@ def sharded_dbscan_fit_resumable(
         frontier = np.zeros((n,), bool)
         frontier[int(np.argmax(todo))] = True
 
-    packed = pack_state(labels, visited, member, core)
+    packed = meter.read(pack_state, labels, visited, member, core)
     result = DBSCANResult(
         labels=finish(packed),
         core_mask=jnp.asarray(core),
@@ -401,7 +416,7 @@ def sharded_dbscan_fit_resumable(
         expansions=jnp.int32(nexp),
         cancelled=cancelled,
     )
-    return result, (_snapshot() if cancelled else None)
+    return result, (_read_snapshot() if cancelled else None)
 
 
 # ---------------------------------------------------------------------------

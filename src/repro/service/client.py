@@ -27,6 +27,7 @@ import numpy as np
 from repro.service.queue import PRIORITY_NORMAL, MiningRequest
 from repro.service.service import ClusteringService
 from repro.service.session import StreamingSession
+from repro.service.trace import new_trace_id
 
 
 class ResultHandle:
@@ -168,9 +169,15 @@ class MiningClient:
         slot.  Raises :class:`BacklogFull` (with ``retry_after``) when the
         queue sheds load.
         """
-        req = self.service._submit(
-            tenant, algo, data, params=params, executor=executor,
-            priority=priority, deadline=deadline, ttl=ttl)
+        # the trace id is minted here so that the whole call is the
+        # request's first span: admission's stages are its children, and
+        # its self time is what the caller waited for outside them
+        trace_id = new_trace_id()
+        with self.service.tracer.begin(trace_id, "submit"):
+            req = self.service._submit(
+                tenant, algo, data, params=params, executor=executor,
+                priority=priority, deadline=deadline, ttl=ttl,
+                trace_id=trace_id)
         return ResultHandle(req)
 
     def stream(

@@ -42,14 +42,17 @@ paradigm knowing how durability works.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
+import types
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
 from repro.core import dbscan, kmeans
+from repro.core.meter import StepMeter
 from repro.runtime import backend as backend_mod
 from repro.service.energy import classify_work, device_class_for
 
@@ -161,11 +164,65 @@ class ExecutionPlan:
 
 
 ItemDone = Callable[[int, np.ndarray, Dict[str, Any]], None]
-ItemState = Callable[[int, Dict[str, np.ndarray]], None]
+# (item index, function that reads the item's mid state back): the batch
+# executor calls the reader inside the checkpoint it feeds
+ItemState = Callable[[int, Callable[[], Dict[str, np.ndarray]]], None]
 
 
 def _cancelled(token) -> bool:
     return token is not None and token.cancelled()
+
+
+class ItemProbe:
+    """How a paradigm reports the work of each item it runs.
+
+    :meth:`steps` wraps one stretch of an item's device loop — from the
+    host-to-device copy of its points to the read-back of its answer —
+    and yields the :class:`~repro.core.meter.StepMeter` the loop counts
+    into; :meth:`span` wraps other work of an item (``host_compute``, a
+    checkpoint).  With a ``tracer``, each becomes a span under the item's
+    trace (``traces[index]``), and a ``steps`` span carries its meter's
+    ``steps``/``syncs``/``sync_s`` as attrs.  A span begun on a thread
+    with none of its trace open is filed under ``parents[trace]`` (the
+    batch's execute span).  The seconds blocked in reads, summed over the
+    batch, are :attr:`sync_s`, with or without a tracer: the batch's
+    measured device time.
+    """
+
+    def __init__(self, tracer=None, traces: Sequence[str] = (),
+                 parents: Optional[Dict[str, str]] = None) -> None:
+        self.tracer = tracer
+        self.traces = traces
+        self.parents = parents if parents is not None else {}
+        self._lock = threading.Lock()
+        self._sync_ns = 0
+
+    @property
+    def sync_s(self) -> float:
+        return self._sync_ns / 1e9
+
+    @contextlib.contextmanager
+    def span(self, index: int, name: str, **attrs: Any) -> Iterator[Any]:
+        trace = (self.traces[index]
+                 if self.tracer is not None and 0 <= index < len(self.traces)
+                 else "")
+        if not trace:
+            yield types.SimpleNamespace(attrs=attrs)   # an untraced item
+            return
+        with self.tracer.begin(trace, name, parent=self.parents.get(trace),
+                               **attrs) as handle:
+            yield handle
+
+    @contextlib.contextmanager
+    def steps(self, index: int, **attrs: Any) -> Iterator[StepMeter]:
+        meter = StepMeter()
+        with self.span(index, "steps", **attrs) as span:
+            try:
+                yield meter
+            finally:
+                span.attrs.update(meter.counters())
+                with self._lock:
+                    self._sync_ns += meter.sync_ns
 
 
 class Paradigm:
@@ -223,12 +280,14 @@ class Paradigm:
         on_item_state: ItemState,
         state_interval: int = 8,
         boundary_hook: Optional[Callable[[], List[ItemView]]] = None,
+        probe: Optional[ItemProbe] = None,
     ) -> RunOutcome:
         """Run the batch's items.  ``boundary_hook``, when given, is polled
         at iteration boundaries (continuous batching): it returns freshly
         joined :class:`ItemView`\\ s — already padded and slotted by the
         batch executor — which the paradigm must fold into the in-flight
-        run.  Paradigms without iteration boundaries ignore it."""
+        run.  Paradigms without iteration boundaries ignore it.  ``probe``
+        receives each item's spans and step counters."""
         raise NotImplementedError
 
 
@@ -264,28 +323,35 @@ class JaxParadigm(Paradigm):
     # -- DBSCAN --------------------------------------------------------------
 
     def _run_dbscan_item(self, item, cfg, token, on_item_done, on_item_state,
-                         state_interval):
+                         state_interval, probe):
         import jax.numpy as jnp
 
         state = (dbscan.DBSCANRunState.from_tree(item.mid_state)
                  if item.mid_state is not None else None)
-        result, run_state = dbscan.fit_resumable(
-            jnp.asarray(item.x_pad), cfg, token,
-            state=state,
-            valid_mask=jnp.arange(item.x_pad.shape[0]) < item.length,
-            on_state=lambda s: on_item_state(item.index, s.as_tree()),
-            state_interval=state_interval,
-        )
+        n_pad, d = item.x_pad.shape
+        with probe.steps(item.index, lane=self.name, algo="dbscan",
+                         n_pad=n_pad, d=d) as meter:
+            result, run_state = dbscan.fit_resumable(
+                jnp.asarray(item.x_pad), cfg, token,
+                state=state,
+                valid_mask=jnp.arange(n_pad) < item.length,
+                on_state=lambda read: on_item_state(
+                    item.index, lambda: read().as_tree()),
+                state_interval=state_interval,
+                meter=meter,
+            )
+            if not result.cancelled:
+                labels = meter.read(np.asarray, result.labels)
+                expansions = meter.read(int, result.expansions)
         if result.cancelled:
             assert run_state is not None
             return RunOutcome(suspended=True, item_index=item.index,
                               mid_state=run_state.as_tree())
-        labels = np.asarray(result.labels)
         real = labels[: item.length]
         on_item_done(item.index, labels, {
             "n_clusters": int(real.max(initial=0)),
             "noise": int(np.sum(real == 0)),
-            "expansions": int(result.expansions),
+            "expansions": expansions,
         })
         return RunOutcome()
 
@@ -310,55 +376,62 @@ class JaxParadigm(Paradigm):
                 "assign": None, "inertia": float("inf"), "stepped": False}
 
     @staticmethod
-    def _kmeans_mid(slot) -> Dict[str, np.ndarray]:
-        return {"centroids": np.asarray(slot["c"], np.float32),
+    def _kmeans_mid(slot, meter: StepMeter) -> Dict[str, np.ndarray]:
+        return {"centroids": meter.read(np.asarray, slot["c"], np.float32),
                 "iteration": np.int32(slot["it"])}
 
-    def _kmeans_finish(self, slot, step, on_item_done, converged) -> None:
+    @staticmethod
+    def _kmeans_answer(slot, step, converged, meter: StepMeter):
+        """The item's labels and scalars, read back from the device."""
         if not slot["stepped"]:
             # resumed at the iteration ceiling: the checkpoint carries
             # centroids, not labels — recover the assignment of the
             # incoming centroids (computed before the update) rather than
             # completing with all-zero labels
             assign, _, _, inertia = step(slot["x"], slot["c"], slot["mask"])
+            meter.steps += 1
             slot["assign"], slot["inertia"] = assign, inertia
-        on_item_done(
-            slot["item"].index, np.asarray(slot["assign"], np.int16), {
-                "inertia": float(slot["inertia"]),
-                "iterations": slot["it"],
-                "converged": bool(converged),
-                "centroids": np.asarray(slot["c"], np.float32),
-            })
+        return meter.read(np.asarray, slot["assign"], np.int16), {
+            "inertia": meter.read(float, slot["inertia"]),
+            "iterations": slot["it"],
+            "converged": bool(converged),
+            "centroids": meter.read(np.asarray, slot["c"], np.float32),
+        }
 
     def _run_kmeans_item(self, item, cfg, token, on_item_done, on_item_state,
-                         state_interval):
-        slot = self._kmeans_slot(item, cfg)
-        step = self.exec_cache.kmeans_step(
-            item.x_pad.shape[0], item.x_pad.shape[1], cfg)
+                         state_interval, probe):
+        n_pad, d = item.x_pad.shape
         converged = False
-        while slot["it"] < cfg.max_iters:
-            if _cancelled(token):
-                return RunOutcome(
-                    suspended=True, item_index=item.index,
-                    mid_state=self._kmeans_mid(slot))
-            assign, c, shift, inertia = step(
-                slot["x"], slot["c"], slot["mask"])
-            slot["assign"], slot["c"], slot["inertia"] = assign, c, inertia
-            slot["stepped"] = True
-            slot["it"] += 1
-            if slot["it"] % state_interval == 0:
-                on_item_state(item.index, self._kmeans_mid(slot))
-            if float(shift) < cfg.tol:
-                converged = True
-                break
-        self._kmeans_finish(slot, step, on_item_done, converged)
+        with probe.steps(item.index, lane=self.name, algo="kmeans",
+                         n_pad=n_pad, d=d) as meter:
+            slot = self._kmeans_slot(item, cfg)
+            step = self.exec_cache.kmeans_step(n_pad, d, cfg)
+            while slot["it"] < cfg.max_iters:
+                if _cancelled(token):
+                    return RunOutcome(
+                        suspended=True, item_index=item.index,
+                        mid_state=self._kmeans_mid(slot, meter))
+                assign, c, shift, inertia = step(
+                    slot["x"], slot["c"], slot["mask"])
+                meter.steps += 1
+                slot["assign"], slot["c"], slot["inertia"] = assign, c, inertia
+                slot["stepped"] = True
+                slot["it"] += 1
+                if slot["it"] % state_interval == 0:
+                    on_item_state(item.index,
+                                  lambda: self._kmeans_mid(slot, meter))
+                if meter.read(float, shift) < cfg.tol:
+                    converged = True
+                    break
+            labels, scalars = self._kmeans_answer(slot, step, converged, meter)
+        on_item_done(item.index, labels, scalars)
         return RunOutcome()
 
     # -- continuous batching -------------------------------------------------
 
     def _execute_kmeans_continuous(self, plan, items, token, on_item_done,
                                    on_item_state, state_interval,
-                                   boundary_hook):
+                                   boundary_hook, probe):
         """Interleaved Lloyd driver: the continuous-batching hot loop.
 
         Every in-flight item runs a quantum of ``state_interval``
@@ -367,7 +440,8 @@ class JaxParadigm(Paradigm):
         futures early), and the boundary hook is polled so compatible
         queued requests join the run in freed slots without waiting for
         the batch to finish.  All items share one compiled step program
-        (same bucket shape), so joining never recompiles.
+        (same bucket shape), so joining never recompiles.  Each quantum
+        is one ``steps`` span of its item.
         """
         from collections import deque
 
@@ -378,38 +452,51 @@ class JaxParadigm(Paradigm):
                 # snapshot EVERY mid-flight slot so the suspension
                 # checkpoint covers the whole in-flight set, not just one
                 for slot in active:
-                    on_item_state(slot["item"].index, self._kmeans_mid(slot))
+                    on_item_state(
+                        slot["item"].index,
+                        lambda s=slot: self._kmeans_mid(s, StepMeter()))
                 return RunOutcome(suspended=True)
             slot = active.popleft()
+            index = slot["item"].index
             cfg = plan.config
-            step = self.exec_cache.kmeans_step(
-                slot["x"].shape[0], slot["x"].shape[1], cfg)
             converged = False
             quantum = 0
-            while slot["it"] < cfg.max_iters and quantum < state_interval:
-                assign, c, shift, inertia = step(
-                    slot["x"], slot["c"], slot["mask"])
-                slot["assign"], slot["c"] = assign, c
-                slot["inertia"] = inertia
-                slot["stepped"] = True
-                slot["it"] += 1
-                quantum += 1
-                if float(shift) < cfg.tol:
-                    converged = True
-                    break
-                # join sub-cadence: claim staged compatible requests every
-                # few iterations, decoupled from the (much coarser)
-                # checkpoint quantum — a joiner's wait is bounded by
-                # iterations, not by how often state is persisted
-                if (boundary_hook is not None
-                        and quantum % _JOIN_POLL_ITERS == 0):
-                    for joined in boundary_hook():
-                        active.append(self._kmeans_slot(joined, cfg))
-            if converged or slot["it"] >= cfg.max_iters:
+            n_pad, d = slot["x"].shape
+            with probe.steps(index, lane=self.name, algo="kmeans",
+                             n_pad=n_pad, d=d) as meter:
+                step = self.exec_cache.kmeans_step(n_pad, d, cfg)
+                while slot["it"] < cfg.max_iters and quantum < state_interval:
+                    assign, c, shift, inertia = step(
+                        slot["x"], slot["c"], slot["mask"])
+                    meter.steps += 1
+                    slot["assign"], slot["c"] = assign, c
+                    slot["inertia"] = inertia
+                    slot["stepped"] = True
+                    slot["it"] += 1
+                    quantum += 1
+                    if meter.read(float, shift) < cfg.tol:
+                        converged = True
+                        break
+                    # join sub-cadence: claim staged compatible requests
+                    # every few iterations, decoupled from the (much
+                    # coarser) checkpoint quantum — a joiner's wait is
+                    # bounded by iterations, not by how often state is
+                    # persisted
+                    if (boundary_hook is not None
+                            and quantum % _JOIN_POLL_ITERS == 0):
+                        for joined in boundary_hook():
+                            active.append(self._kmeans_slot(joined, cfg))
+                done = converged or slot["it"] >= cfg.max_iters
+                if done:
+                    labels, scalars = self._kmeans_answer(
+                        slot, step, converged, meter)
+                else:
+                    on_item_state(index,
+                                  lambda: self._kmeans_mid(slot, meter))
+            if done:
                 # early retirement: labels delivered before the batch ends
-                self._kmeans_finish(slot, step, on_item_done, converged)
+                on_item_done(index, labels, scalars)
             else:
-                on_item_state(slot["item"].index, self._kmeans_mid(slot))
                 active.append(slot)
             if boundary_hook is not None:
                 for joined in boundary_hook():
@@ -417,8 +504,9 @@ class JaxParadigm(Paradigm):
         return RunOutcome()
 
     def execute(self, plan, items, token, on_item_done, on_item_state,
-                state_interval=8, boundary_hook=None):
+                state_interval=8, boundary_hook=None, probe=None):
         backend_mod.discover_backend()  # lazy-load before first device use
+        probe = probe or ItemProbe()
         cfg = plan.config if plan.config is not None else self._config(
             plan.algo, plan.params)
         if plan.config is None:
@@ -426,7 +514,7 @@ class JaxParadigm(Paradigm):
         if plan.algo != "dbscan" and boundary_hook is not None:
             return self._execute_kmeans_continuous(
                 plan, items, token, on_item_done, on_item_state,
-                state_interval, boundary_hook)
+                state_interval, boundary_hook, probe)
         run_item = (self._run_dbscan_item if plan.algo == "dbscan"
                     else self._run_kmeans_item)
         from collections import deque
@@ -437,7 +525,7 @@ class JaxParadigm(Paradigm):
                 return RunOutcome(suspended=True)
             item = work.popleft()
             outcome = run_item(item, cfg, token, on_item_done, on_item_state,
-                               state_interval)
+                               state_interval, probe)
             if outcome.suspended:
                 return outcome
             if boundary_hook is not None:
@@ -520,9 +608,10 @@ class NumpyMTParadigm(Paradigm):
         }
 
     def execute(self, plan, items, token, on_item_done, on_item_state,
-                state_interval=8, boundary_hook=None):
+                state_interval=8, boundary_hook=None, probe=None):
         # no iteration-boundary joins: the thread pool runs items to
         # completion, so a continuous hook is ignored (batcher re-forms)
+        probe = probe or ItemProbe()
         cfg = plan.config if plan.config is not None else self._config(
             plan.algo, plan.params)
         work = (self._dbscan_item if plan.algo == "dbscan"
@@ -533,7 +622,10 @@ class NumpyMTParadigm(Paradigm):
             if _cancelled(token):
                 suspended.set()
                 return
-            labels, scalars = work(item, cfg)
+            with probe.span(item.index, "host_compute", algo=plan.algo,
+                            n=item.length, d=item.x_pad.shape[1]) as span:
+                labels, scalars = work(item, cfg)
+                span.attrs["iterations"] = int(scalars.get("iterations", 0))
             if _cancelled(token):
                 # completed anyway; still record it so resume skips the item
                 suspended.set()
@@ -645,7 +737,7 @@ class DistributedParadigm(Paradigm):
     # -- items ---------------------------------------------------------------
 
     def _kmeans_item(self, mesh, plan, item, token, on_item_done,
-                     on_item_state, state_interval):
+                     on_item_state, state_interval, probe):
         import jax
         import jax.numpy as jnp
 
@@ -655,36 +747,41 @@ class DistributedParadigm(Paradigm):
         n_max = item.x_pad.shape[0]
         x_sh = self._pad_to_shards(item.x_pad, plan)
         mask = np.arange(x_sh.shape[0]) < item.length
-        if item.mid_state is not None:
-            c0 = np.asarray(item.mid_state["centroids"], np.float32)
-            it0 = int(item.mid_state["iteration"])
-        else:
-            # identical seeding to the single-device paradigms: an
-            # oversized request's labels match the unsharded reference
-            c0 = np.asarray(kmeans.init_centroids(
-                jax.random.PRNGKey(item.seed),
-                jnp.asarray(item.x_pad[: item.length]), cfg))
-            it0 = 0
-        result, mid = dist.sharded_kmeans_fit_resumable(
-            mesh, x_sh, mask, cfg, token,
-            centroids=c0, start_iteration=it0,
-            on_state=lambda s: on_item_state(item.index, s),
-            state_interval=state_interval,
-        )
-        if result.cancelled:
-            return RunOutcome(suspended=True, item_index=item.index,
-                              mid_state=mid)
-        labels = np.asarray(result.labels)[:n_max].astype(np.int16)
-        on_item_done(item.index, labels, {
-            "inertia": float(result.inertia),
-            "iterations": int(result.iterations),
-            "converged": bool(result.converged),
-            "centroids": np.asarray(result.centroids, np.float32),
-        })
+        with probe.steps(item.index, lane=self.name, algo="kmeans",
+                         n_pad=x_sh.shape[0], d=x_sh.shape[1]) as meter:
+            if item.mid_state is not None:
+                c0 = np.asarray(item.mid_state["centroids"], np.float32)
+                it0 = int(item.mid_state["iteration"])
+            else:
+                # identical seeding to the single-device paradigms: an
+                # oversized request's labels match the unsharded reference
+                c0 = meter.read(np.asarray, kmeans.init_centroids(
+                    jax.random.PRNGKey(item.seed),
+                    jnp.asarray(item.x_pad[: item.length]), cfg))
+                it0 = 0
+            result, mid = dist.sharded_kmeans_fit_resumable(
+                mesh, x_sh, mask, cfg, token,
+                centroids=c0, start_iteration=it0,
+                on_state=lambda read: on_item_state(item.index, read),
+                state_interval=state_interval,
+                meter=meter,
+            )
+            if result.cancelled:
+                return RunOutcome(suspended=True, item_index=item.index,
+                                  mid_state=mid)
+            labels = meter.read(np.asarray, result.labels)
+            scalars = {
+                "inertia": meter.read(float, result.inertia),
+                "iterations": meter.read(int, result.iterations),
+                "converged": meter.read(bool, result.converged),
+                "centroids": meter.read(np.asarray, result.centroids,
+                                        np.float32),
+            }
+        on_item_done(item.index, labels[:n_max].astype(np.int16), scalars)
         return RunOutcome()
 
     def _dbscan_item(self, mesh, plan, item, token, on_item_done,
-                     on_item_state, state_interval):
+                     on_item_state, state_interval, probe):
         from repro.core import distributed as dist
 
         cfg = plan.config
@@ -697,38 +794,43 @@ class DistributedParadigm(Paradigm):
                 dbscan.DBSCANRunState.from_tree(item.mid_state), n_pad)
         valid = np.arange(n_pad) < item.length
 
-        def report(s: dbscan.DBSCANRunState) -> None:
+        def report(read) -> None:
             # checkpoints carry the (n_max,) view — mesh-shape independent
-            on_item_state(item.index,
-                          self._resize_dbscan_state(s, n_max).as_tree())
+            on_item_state(item.index, lambda: self._resize_dbscan_state(
+                read(), n_max).as_tree())
 
-        result, run_state = dist.sharded_dbscan_fit_resumable(
-            mesh, x_sh, cfg, token,
-            state=state, valid_mask=valid,
-            on_state=report, state_interval=state_interval,
-            axis=self.axis,
-        )
-        if result.cancelled:
-            assert run_state is not None
-            return RunOutcome(
-                suspended=True, item_index=item.index,
-                mid_state=self._resize_dbscan_state(
-                    run_state, n_max).as_tree())
-        labels = np.asarray(result.labels)[:n_max].astype(np.int16)
+        with probe.steps(item.index, lane=self.name, algo="dbscan",
+                         n_pad=n_pad, d=x_sh.shape[1]) as meter:
+            result, run_state = dist.sharded_dbscan_fit_resumable(
+                mesh, x_sh, cfg, token,
+                state=state, valid_mask=valid,
+                on_state=report, state_interval=state_interval,
+                axis=self.axis, meter=meter,
+            )
+            if result.cancelled:
+                assert run_state is not None
+                return RunOutcome(
+                    suspended=True, item_index=item.index,
+                    mid_state=self._resize_dbscan_state(
+                        run_state, n_max).as_tree())
+            labels = meter.read(np.asarray, result.labels)
+            expansions = meter.read(int, result.expansions)
+        labels = labels[:n_max].astype(np.int16)
         real = labels[: item.length]
         on_item_done(item.index, labels, {
             "n_clusters": int(real.max(initial=0)),
             "noise": int(np.sum(real == 0)),
-            "expansions": int(result.expansions),
+            "expansions": expansions,
         })
         return RunOutcome()
 
     def execute(self, plan, items, token, on_item_done, on_item_state,
-                state_interval=8, boundary_hook=None):
+                state_interval=8, boundary_hook=None, probe=None):
         # oversized requests run one-at-a-time across the mesh; nothing
         # can share the device, so boundary joins don't apply
         from repro.core import distributed as dist
 
+        probe = probe or ItemProbe()
         backend_mod.discover_backend()
         mesh = dist.local_mesh(self.axis)
         run_item = (self._dbscan_item if plan.algo == "dbscan"
@@ -737,7 +839,7 @@ class DistributedParadigm(Paradigm):
             if _cancelled(token):
                 return RunOutcome(suspended=True)
             outcome = run_item(mesh, plan, item, token, on_item_done,
-                               on_item_state, state_interval)
+                               on_item_state, state_interval, probe)
             if outcome.suspended:
                 return outcome
         return RunOutcome()

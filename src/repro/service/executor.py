@@ -36,11 +36,13 @@ from repro.runtime.preemption import HoldAlive
 from repro.service.batcher import MicroBatch
 from repro.service.dispatch import (
     ExecutionPlan,
+    ItemProbe,
     ItemView,
     ParadigmRegistry,
     default_registry,
     far_diagonal_pad,
 )
+from repro.service.trace import wall_now
 
 logger = logging.getLogger(__name__)
 
@@ -64,8 +66,10 @@ class BatchOutcome:
     cache_keys: Optional[List[str]] = None          # per item content hashes
     plan: Optional[Dict[str, Any]] = None           # ExecutionPlan.summary()
     lengths: Optional[List[int]] = None             # per item real points
-    host_s: float = 0.0     # exec wall time spent in host bookkeeping
-    device_s: float = 0.0   # exec_s minus host_s (the compute share)
+    host_s: float = 0.0     # exec wall time spent writing checkpoints
+    # seconds the host blocked in device-to-host reads, summed over the
+    # items (their steps spans' sync_s): measured, 0 on numpy-mt
+    device_s: float = 0.0
     continuous: bool = False  # ran with in-flight join/retire slots
     joined: int = 0           # requests that joined mid-flight
     retired: int = 0          # items delivered before the batch ended
@@ -122,9 +126,10 @@ class BatchExecutor:
         self.on_batch_durable: Optional[
             Callable[[int, List[Any]], None]] = None
         # optional RequestTracer (see repro.service.trace): when attached,
-        # plan / execute-attempt / checkpoint / resume spans are emitted
-        # under each request's trace id — which rides in the job record,
-        # so a resumed batch in a NEW process continues the same traces
+        # plan / execute-attempt / steps / checkpoint / resume spans are
+        # emitted under each request's trace id — which rides in the job
+        # record, so a resumed batch in a NEW process continues the same
+        # traces
         self.tracer = None
 
     def _ckpt(self, job_id: int) -> CheckpointStore:
@@ -187,7 +192,7 @@ class BatchExecutor:
         # phase one of the plan/execute contract: placement, shard layout,
         # cost + modeled joules — persisted with the job so the routing
         # decision is inspectable after the fact
-        t_plan = time.time()
+        t_plan = wall_now()
         m_plan = time.monotonic()
         plan = self.registry.get(executor).plan(
             key.algo, params, batch_size=size, n_max=n_max, features=d,
@@ -352,7 +357,8 @@ class BatchExecutor:
 
         last_write = [0.0, ""]   # monotonic time of last write, its path
 
-        def save(item: Optional[int] = None) -> str:
+        def save() -> Dict[str, Any]:
+            """Write the batch state; the checkpoint span's attrs."""
             # continuous write coalescing: the in-memory state is always
             # current, so skipping a write costs only resume granularity
             # (the WAL keeps every unresolved request replayable).  A
@@ -363,45 +369,54 @@ class BatchExecutor:
                     and (token is None or not token.cancelled())
                     and time.monotonic() - last_write[0]
                     < self.cont_save_interval_s):
-                return last_write[1]
+                return {"step": save_step[0], "written": False}
             # every checkpoint is self-contained (data rides along), so GC
             # of old steps can never strand a resume
             save_step[0] += 1
-            t_wall = time.time()
             m0 = time.monotonic()
             path = ckpt.save(save_step[0], state, metadata={"params": jp})
             last_write[0], last_write[1] = time.monotonic(), path
             self.jobs.report_progress(job_id, step=save_step[0],
                                       checkpoint_path=path)
-            dur = time.monotonic() - m0
-            host[0] += dur
-            if (tr is not None and item is not None
-                    and 0 <= item < len(traces) and traces[item]):
-                tr.emit(traces[item], "checkpoint", t_wall, dur,
-                        executor=jp["executor"], job_id=job_id,
-                        step=save_step[0])
-            return path
+            host[0] += time.monotonic() - m0
+            return {"step": save_step[0], "written": True}
 
-        def on_item_state(i: int, tree: Dict[str, np.ndarray]) -> None:
-            with lock:
-                if cont_slots:
-                    state["slot.centroids"][i] = np.asarray(
-                        tree["centroids"], np.float32)
-                    state["slot.iteration"][i] = np.int32(tree["iteration"])
-                    state["slot.started"][i] = True
-                else:
-                    state["active"] = np.asarray(True)
-                    state["item"] = np.int32(i)
-                    for k, v in tree.items():
-                        state[f"mid.{k}"] = np.asarray(v)
-                save(i)
+        # the live traces' execute spans (opened below): a span of an item
+        # begun on another thread (numpy-mt's pool) is filed under them
+        exec_parents: Dict[str, str] = {}
+        probe = ItemProbe(tr, traces, exec_parents)
+
+        def checkpoint(i: int):
+            # one span per save of item i, parented to its open steps span:
+            # the device read-back that feeds it, the fold into the batch
+            # state, and the write (unless coalesced away)
+            return probe.span(i, "checkpoint", executor=jp["executor"],
+                              job_id=job_id)
+
+        def on_item_state(i: int,
+                          read: Callable[[], Dict[str, np.ndarray]]) -> None:
+            with checkpoint(i) as span:
+                tree = read()
+                with lock:
+                    if cont_slots:
+                        state["slot.centroids"][i] = np.asarray(
+                            tree["centroids"], np.float32)
+                        state["slot.iteration"][i] = np.int32(
+                            tree["iteration"])
+                        state["slot.started"][i] = True
+                    else:
+                        state["active"] = np.asarray(True)
+                        state["item"] = np.int32(i)
+                        for k, v in tree.items():
+                            state[f"mid.{k}"] = np.asarray(v)
+                    span.attrs.update(save())
             events[0] += 1
             if progress_hook is not None:
                 progress_hook(job_id, i, events[0])
 
         def on_item_done(i: int, labels: np.ndarray,
                          scalars: Dict[str, Any]) -> None:
-            with lock:
+            with checkpoint(i) as span, lock:
                 state["labels"][i] = labels.astype(np.int16)
                 state["done"][i] = True
                 state["active"] = np.asarray(False)
@@ -412,7 +427,7 @@ class BatchExecutor:
                              "n_clusters", "noise", "expansions"):
                     if name in scalars:
                         state[name][i] = scalars[name]
-                save(i)
+                span.attrs.update(save())
                 result = (self._item_result(jp, state, i)
                           if on_retire is not None else None)
             events[0] += 1
@@ -529,6 +544,7 @@ class BatchExecutor:
                 exec_spans.append(tr.begin(
                     tid, "execute", announce=True, executor=jp["executor"],
                     job_id=job_id, resumed=resumed))
+                exec_parents[tid] = exec_spans[-1].span_id
 
         t0 = time.time()
         hb = max(0.05, min(1.0, self.jobs.heartbeat_timeout / 4.0))
@@ -538,16 +554,17 @@ class BatchExecutor:
                 outcome = paradigm.execute(
                     plan, items, token, on_item_done, on_item_state,
                     state_interval=self.checkpoint_every,
-                    boundary_hook=boundary,
+                    boundary_hook=boundary, probe=probe,
                 )
             except BaseException as e:
                 error = e
         exec_s = time.time() - t0
-        # host/device split: checkpointing + progress reporting is host
-        # bookkeeping; the remainder of the exec window is the paradigm's
-        # compute share (kernel launches, device sync, result copies)
+        # host/device split: host_s is checkpoint writing; device_s is
+        # what the items' loops measured blocked on the device (their
+        # reads of step results and answers) — neither is modeled, and
+        # the rest of exec_s is dispatch and other host work
         host_s = min(host[0], exec_s)
-        device_s = max(0.0, exec_s - host_s)
+        device_s = min(probe.sync_s, exec_s)
 
         if error is not None:
             for h in exec_spans:

@@ -92,8 +92,8 @@ class BatchRecord:
     exec_s: float
     resumed: bool
     real_points: int = 0       # sum of item lengths (0 = not reported)
-    host_s: float = 0.0        # exec time spent on host work (checkpoints)
-    device_s: float = 0.0      # exec_s minus host bookkeeping
+    host_s: float = 0.0        # exec time spent writing checkpoints
+    device_s: float = 0.0      # exec time blocked reading device results
     device_class: str = ""     # energy.DEVICE_CLASSES key (from the plan)
 
     @property
@@ -148,8 +148,12 @@ class ServiceMetrics:
         # -- bucketing scorecard (lifetime) ---------------------------------
         # real vs padded points executed, and the distinct compiled-program
         # shapes seen: each fresh (executor, algo, features, n_max) combo
-        # is a jit compile the executable cache must hold — the recompile
+        # is a program the executable cache must hold — the recompile
         # axis of the bucketing tradeoff (padding waste vs cache misses).
+        # ``recompiles`` counts those shapes, not compiles: a shape warmed
+        # at start-up or read from the persistent cache still counts once
+        # (the backend's own compiles are the service snapshot's
+        # ``backend.compiles``, one ``compile`` span each).
         # LRU-bounded: a long-lived service admitting arbitrary shapes must
         # not grow this without limit, so the oldest-seen shape is evicted
         # past ``max_tracked_shapes`` (counted in ``shape_evictions``); an

@@ -64,7 +64,8 @@ from repro.service.queue import (
     RequestDropped,
 )
 from repro.service.telemetry import EventLog, SLOEvaluator
-from repro.service.trace import RequestTracer, new_trace_id, read_spans
+from repro.service.trace import (RequestTracer, new_trace_id, read_spans,
+                                 wall_now)
 from repro.service.wal import RequestLog
 
 logger = logging.getLogger(__name__)
@@ -633,10 +634,12 @@ class ClusteringService:
                     "admission log persists them as JSON); use "
                     "lists/scalars instead of tuples or non-string keys")
         req.cache_key = content_key(algo, req.params, data)
-        t_c, m_c = time.time(), time.monotonic()
+        t_c, m_c = wall_now(), time.monotonic()
         cached = self.cache.get(req.cache_key)
         self.tracer.emit(req.trace_id, "cache_lookup", t_c,
-                         time.monotonic() - m_c, hit=cached is not None)
+                         time.monotonic() - m_c,
+                         parent=self.tracer.open_span_id(req.trace_id),
+                         hit=cached is not None)
         if cached is not None:
             req.cache_hit = True
             req.resolve(cached)
@@ -684,7 +687,7 @@ class ClusteringService:
                 with self._lock:
                     self._inflight.pop(req.request_id, None)
                 raise
-        t_e, m_e = time.time(), time.monotonic()
+        t_e, m_e = wall_now(), time.monotonic()
         try:
             with self._lock:
                 # check-and-enqueue under the same lock stop() takes before
@@ -718,6 +721,7 @@ class ClusteringService:
             return req
         self.tracer.emit(req.trace_id, "enqueue", t_e,
                          time.monotonic() - m_e,
+                         parent=self.tracer.open_span_id(req.trace_id),
                          config_epoch=self._config_epoch)
         req.add_done_callback(self._request_done)
         return req
@@ -901,14 +905,16 @@ class ClusteringService:
             def on_retire(req: MiningRequest, result: Dict[str, Any]) -> None:
                 # the early-retirement delivery path: fires mid-batch from
                 # the executor the moment an item's labels exist
-                t_d, m_d = time.time(), time.monotonic()
+                t_d, m_d = wall_now(), time.monotonic()
                 if req.cache_key:
                     self.cache.put(req.cache_key, result)
                 req.resolve(result)
                 if req.trace_id:
-                    self.tracer.emit(req.trace_id, "deliver", t_d,
-                                     time.monotonic() - m_d,
-                                     executor=executor)
+                    self.tracer.emit(
+                        req.trace_id, "deliver", t_d,
+                        time.monotonic() - m_d,
+                        parent=self.tracer.open_span_id(req.trace_id),
+                        executor=executor)
                 self.metrics.record_request(
                     tenant=req.tenant, algo=req.algo, executor=executor,
                     latency_s=req.latency or 0.0,
@@ -993,7 +999,7 @@ class ClusteringService:
                     f"request {req.request_id} missing from batch "
                     f"{outcome.job_id} results")))
                 continue
-            t_d, m_d = time.time(), time.monotonic()
+            t_d, m_d = wall_now(), time.monotonic()
             if req.cache_key:
                 self.cache.put(req.cache_key, result)
             req.resolve(result)
@@ -1469,6 +1475,10 @@ class ClusteringService:
         snap["slo"] = self.slo.evaluate(
             ws["latencies"], ws["failures"], ws["outcomes"])
         snap["trace"] = self.tracer.stats()
+        # backend compiles (or compile-cache reads) a request waited for:
+        # each is a ``compile`` span; ``bucketing.recompiles`` counts
+        # distinct padded shapes seen, not compiles
+        snap["backend"] = {"compiles": self.tracer.compiles}
         snap["events"] = (self.events.stats()
                           if self.events is not None else None)
         return snap

@@ -201,6 +201,11 @@ def render_prometheus(snapshot: Dict[str, Any], prefix: str = "repro") -> str:
         if key in bucketing:
             out.add(f"bucketing_{key}", bucketing[key],
                     help_text=f"Bucketing {key}", kind=kind)
+    backend = snapshot.get("backend") or {}
+    if "compiles" in backend:
+        out.add("backend_compiles_total", backend["compiles"],
+                help_text="Backend compiles (or compile-cache reads) inside "
+                          "a request's span", kind="counter")
     cache = snapshot.get("cache") or {}
     for key in ("entries", "hits", "misses", "disk_hits"):
         if key in cache:

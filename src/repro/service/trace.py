@@ -13,8 +13,26 @@ emits a *span* into a bounded ring buffer.
 Design:
 
 - **Spans are cheap and immutable.**  A span is (trace_id, name, wall
-  start, duration, pid/tid, attrs).  Durations are measured on the
-  monotonic clock; the wall timestamp is only for display alignment.
+  start, duration, pid/tid, parent, attrs).  The start is read from one
+  clock, ``time.time_ns()`` — the wall clock the profiler's device
+  events are placed on — and durations on the monotonic clock.  ``tid``
+  is the OS thread id (``threading.get_native_id``), as on the
+  profiler's host lines.
+- **Parents by thread.**  A span opened with :meth:`RequestTracer.begin`
+  records as ``parent`` the innermost span of its trace still open on the
+  same thread (else the ``parent=`` it was given), so a reader can take a
+  stage's self time: its duration minus what its children cover.  Work
+  that must not cost a span per step (a Lloyd iteration, a DBSCAN
+  expansion) is counted in its item's span attrs instead.
+- **Mirrored into the profiler.**  A span entered as a context manager
+  also enters a ``jax.profiler.TraceAnnotation`` named after the stage
+  (with its ``trace_id``), so an operator's own ``jax.profiler`` capture
+  shows the service's stages above the device operations.  With no
+  capture running this costs one enabled-check per span.
+- **Compiles are spans.**  One process-wide ``jax.monitoring`` listener
+  turns each backend compile (or persistent-cache read) into a
+  ``compile`` span under the innermost span open on the compiling
+  thread — the request that waited for it.
 - **Bounded ring.**  Completed spans land in a ``deque(maxlen=capacity)``;
   overflow evicts the oldest and counts ``dropped`` — a long-lived
   service never grows tracing state without bound.
@@ -39,6 +57,7 @@ import json
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable, Deque, Dict, Iterable, List, Optional
 
@@ -48,6 +67,9 @@ from typing import Any, Callable, Deque, Dict, Iterable, List, Optional
 DEFAULT_CAPACITY = 4096
 
 _SPAN_IDS = itertools.count(1)
+
+# JAX's event for one backend compile (or persistent compile-cache read)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def new_trace_id() -> str:
@@ -65,13 +87,15 @@ class Span:
     dur_s: float               # measured on the monotonic clock
     span_id: str = ""
     pid: int = 0
-    tid: int = 0
+    tid: int = 0               # OS thread id
+    parent: Optional[str] = None   # span_id of the enclosing span
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def as_dict(self) -> Dict[str, Any]:
         return {
             "trace_id": self.trace_id,
             "span_id": self.span_id,
+            "parent": self.parent,
             "name": self.name,
             "t0": self.t0,
             "dur_s": self.dur_s,
@@ -86,28 +110,57 @@ def _new_span_id() -> str:
     return f"{os.getpid():x}-{next(_SPAN_IDS)}"
 
 
+def wall_now() -> float:
+    """Epoch seconds from the one clock every span start is read from."""
+    return time.time_ns() / 1e9
+
+
+_ANNOTATION = None
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` (whose ``is_enabled`` says whether
+    a profiler capture is running), or False where jax cannot be
+    imported."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
 class SpanHandle:
     """In-flight span: created by :meth:`RequestTracer.begin`, completed by
     :meth:`finish` (or by exiting it as a context manager — an exception
-    completes the span with an ``error`` attr and propagates)."""
+    completes the span with an ``error`` attr and propagates).  Its
+    ``attrs`` may be updated until it finishes (counters are added so)."""
 
     def __init__(self, tracer: "RequestTracer", trace_id: str, name: str,
-                 attrs: Dict[str, Any], announce: bool) -> None:
+                 attrs: Dict[str, Any], announce: bool,
+                 parent: Optional[str] = None) -> None:
         self._tracer = tracer
         self.trace_id = trace_id
         self.name = name
         self.attrs = attrs
         self.span_id = _new_span_id()
-        self.t0 = time.time()
+        self.parent = tracer.open_span_id(trace_id) or parent
+        self._stack = tracer._stack()
+        self._stack.append(self)
+        self.t0 = wall_now()
         self._t0_mono = time.monotonic()
         self._done = False
+        self._annotation = None
         if announce:
             # journal the start: if this process dies mid-span (SIGKILL),
             # the flushed start event is the only evidence the attempt ran
             tracer._sink_event("span_start", {
                 "trace_id": trace_id, "span_id": self.span_id,
-                "name": name, "t0": self.t0, "dur_s": None,
-                "pid": os.getpid(), "tid": threading.get_ident() & 0xFFFF,
+                "parent": self.parent, "name": name, "t0": self.t0,
+                "dur_s": None, "pid": os.getpid(),
+                "tid": threading.get_native_id(),
                 "attrs": dict(attrs), "phase": "start",
             })
 
@@ -115,14 +168,28 @@ class SpanHandle:
         if self._done:
             return None
         self._done = True
+        dur = time.monotonic() - self._t0_mono
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        try:
+            self._stack.remove(self)
+        except ValueError:
+            pass
         merged = dict(self.attrs)
         merged.update(attrs)
         return self._tracer.emit(
-            self.trace_id, self.name, self.t0,
-            time.monotonic() - self._t0_mono,
-            span_id=self.span_id, **merged)
+            self.trace_id, self.name, self.t0, dur,
+            span_id=self.span_id, parent=self.parent, **merged)
 
     def __enter__(self) -> "SpanHandle":
+        annotation = _annotation_class()
+        if annotation and annotation.is_enabled():
+            self._annotation = annotation(self.name, trace_id=self.trace_id)
+            self._annotation.__enter__()
+            # the span starts with its mirror, so that a thread switch
+            # since begin() cannot put the two apart
+            self.t0 = wall_now()
+            self._t0_mono = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, _tb) -> None:
@@ -152,6 +219,30 @@ class RequestTracer:
         self._spans: Deque[Span] = deque(maxlen=self.capacity)
         self.dropped = 0           # ring evictions (oldest span lost)
         self.emitted = 0           # completed spans ever recorded
+        self.compiles = 0          # backend compiles inside this tracer's spans
+        # per thread: the spans begun on it and not yet finished, oldest
+        # first — the innermost one is the parent of the next begin
+        self._open = threading.local()
+        _install_compile_listener(self)
+
+    def _stack(self) -> List[SpanHandle]:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def innermost(self) -> Optional[SpanHandle]:
+        """The innermost span open on the calling thread, if any."""
+        stack = getattr(self._open, "stack", None)
+        return stack[-1] if stack else None
+
+    def open_span_id(self, trace_id: str) -> Optional[str]:
+        """``span_id`` of the innermost span of ``trace_id`` open on the
+        calling thread: the parent for a retroactive span emitted there."""
+        for handle in reversed(getattr(self._open, "stack", None) or ()):
+            if handle.trace_id == trace_id:
+                return handle.span_id
+        return None
 
     # -- emission ------------------------------------------------------------
 
@@ -164,15 +255,18 @@ class RequestTracer:
             pass
 
     def emit(self, trace_id: str, name: str, t0: float, dur_s: float,
-             span_id: Optional[str] = None, **attrs: Any) -> Span:
+             span_id: Optional[str] = None, parent: Optional[str] = None,
+             **attrs: Any) -> Span:
         """Record a completed span (retroactive timestamps allowed — the
         queue-wait span is emitted at batch-claim time from the request's
-        own submit/stage timestamps)."""
+        own submit/stage timestamps).  A retroactive span names its
+        ``parent`` explicitly, or has none."""
         span = Span(trace_id=trace_id, name=name, t0=float(t0),
                     dur_s=max(0.0, float(dur_s)),
                     span_id=span_id or _new_span_id(),
                     pid=os.getpid(),
-                    tid=threading.get_ident() & 0xFFFF,
+                    tid=threading.get_native_id(),
+                    parent=parent,
                     attrs=attrs)
         with self._lock:
             if len(self._spans) >= self.capacity:
@@ -184,13 +278,22 @@ class RequestTracer:
 
     def mark(self, trace_id: str, name: str, **attrs: Any) -> Span:
         """Zero-duration marker span (e.g. the resume boundary)."""
-        return self.emit(trace_id, name, time.time(), 0.0, **attrs)
+        return self.emit(trace_id, name, wall_now(), 0.0, **attrs)
 
     def begin(self, trace_id: str, name: str, announce: bool = False,
-              **attrs: Any) -> SpanHandle:
+              parent: Optional[str] = None, **attrs: Any) -> SpanHandle:
         """Open an in-flight span; ``announce=True`` journals the start to
-        the sink so a SIGKILL mid-span still leaves evidence on disk."""
-        return SpanHandle(self, trace_id, name, attrs, announce)
+        the sink so a SIGKILL mid-span still leaves evidence on disk.
+        ``parent`` files the span under a span of another thread (work
+        handed to a pool) when none of its trace is open on this one."""
+        return SpanHandle(self, trace_id, name, attrs, announce, parent)
+
+    def _on_compile(self, handle: SpanHandle, t0: float, t1: float,
+                    attrs: Dict[str, Any]) -> None:
+        with self._lock:
+            self.compiles += 1
+        self.emit(handle.trace_id, "compile", t0, t1 - t0,
+                  parent=handle.span_id, **attrs)
 
     # -- inspection ----------------------------------------------------------
 
@@ -216,6 +319,51 @@ class RequestTracer:
             }
 
 
+# -- compile spans --------------------------------------------------------------
+
+# every live tracer, for the one process-wide compile listener to search
+_TRACERS: "weakref.WeakSet[RequestTracer]" = weakref.WeakSet()
+_LISTENER_LOCK = threading.Lock()
+_LISTENER_INSTALLED = False
+
+
+def _on_compile_span(event: str, start: float, end: float,
+                     **kwargs: Any) -> None:
+    """``jax.monitoring`` time-span listener: a backend compile becomes a
+    ``compile`` span under the innermost span open on this (the
+    compiling) thread, in that span's tracer.  A compile with no span
+    open on its thread (warm-up, tests) is not any request's, and is not
+    recorded."""
+    if event != COMPILE_EVENT:
+        return
+    best = None
+    for tracer in list(_TRACERS):
+        handle = tracer.innermost()
+        if handle is not None and (best is None or handle.t0 > best[1].t0):
+            best = (tracer, handle)
+    if best is None:
+        return
+    attrs = {"fun_name": str(kwargs.get("fun_name", ""))}
+    try:
+        best[0]._on_compile(best[1], float(start), float(end), attrs)
+    except Exception:
+        pass   # telemetry must never fail the compile it observed
+
+
+def _install_compile_listener(tracer: RequestTracer) -> None:
+    global _LISTENER_INSTALLED
+    _TRACERS.add(tracer)
+    with _LISTENER_LOCK:
+        if _LISTENER_INSTALLED:
+            return
+        try:
+            import jax.monitoring
+        except ImportError:
+            return
+        jax.monitoring.register_event_time_span_listener(_on_compile_span)
+        _LISTENER_INSTALLED = True
+
+
 # -- export / cross-process merge ---------------------------------------------
 
 
@@ -236,7 +384,8 @@ def chrome_trace(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
             "ts": float(s["t0"]) * 1e6,          # microseconds
             "pid": int(s.get("pid", 0)),
             "tid": int(s.get("tid", 0)),
-            "args": dict(s.get("attrs") or {}, trace_id=s["trace_id"]),
+            "args": dict(s.get("attrs") or {}, trace_id=s["trace_id"],
+                         span_id=s.get("span_id"), parent=s.get("parent")),
         }
         if s.get("phase") == "start" or s.get("dur_s") is None:
             ev["ph"] = "B"
@@ -291,6 +440,7 @@ def read_spans(events_root: str,
                 merged[sid] = {
                     "trace_id": rec.get("trace_id"),
                     "span_id": sid,
+                    "parent": rec.get("parent"),
                     "name": rec.get("name"),
                     "t0": rec.get("t0"),
                     "dur_s": rec.get("dur_s"),

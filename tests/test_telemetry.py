@@ -484,7 +484,7 @@ def test_snapshot_has_stage_breakdown_and_host_device_split(tmp_path):
     with svc:
         hs = [client.submit(f"t{i}", "kmeans", pts(20 + i),
                             params={"k": 3, "seed": i},
-                            executor="jax-ref")
+                            executor=("numpy-mt" if i == 3 else "jax-ref"))
               for i in range(4)]
         for h in hs:
             h.result(300)
@@ -492,8 +492,11 @@ def test_snapshot_has_stage_breakdown_and_host_device_split(tmp_path):
     assert {"execute", "wal_append", "queue_wait",
             "deliver"} <= set(snap["stages"])
     ex = snap["by_executor"]["jax-ref"]
+    # device_s is measured (seconds blocked reading device results), not
+    # the remainder of exec_s, so host_s + device_s no longer sums to it
     assert ex["host_s"] > 0.0 and ex["device_s"] > 0.0
-    assert ex["host_s"] + ex["device_s"] == pytest.approx(ex["exec_s"])
+    assert 0.0 <= ex["device_s"] <= ex["exec_s"]
+    assert snap["by_executor"]["numpy-mt"]["device_s"] == 0.0
     assert snap["slo"]["window_requests"] == 4
     assert snap["trace"]["dropped"] == 0
     assert snap["events"]["written"] > 0
