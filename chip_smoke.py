@@ -225,10 +225,13 @@ def _compile_kernels(n: int):
     dcfg = _dbscan_config(_dbscan_params(), use_kernel=True)
     x = jax.ShapeDtypeStruct((n, FEATURES), jnp.float32)
     f = jax.ShapeDtypeStruct((n,), jnp.bool_)
+    i = jax.ShapeDtypeStruct((), jnp.int32)
+    carry = (jax.ShapeDtypeStruct((n,), jnp.int32), f, f, f, i, i)
     degree, seconds["dbscan_degree"] = _timed_compile(
         dbscan._degree_step, x, cfg=dcfg)
+    # the expansion kernel inside the device loop the host loop dispatches
     expand, seconds["dbscan_expand"] = _timed_compile(
-        dbscan._expand_step, x, f, cfg=dcfg)
+        dbscan._expand_steps, x, f, carry, i, cfg=dcfg)
     texts["dbscan_degree"] = degree.as_text()
     texts["dbscan_expand"] = expand.as_text()
     for name, text in texts.items():

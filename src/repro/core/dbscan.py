@@ -6,7 +6,7 @@ Paper semantics (§II.C):
 - two accelerator kernels "that have almost the same purpose": core-point
   reachability in the main loop and cluster expansion — here
   :func:`repro.kernels.neighbor.epsilon_degree` and
-  :func:`repro.kernels.neighbor.expand_frontier`;
+  :func:`repro.kernels.neighbor.ops.expand_padded`;
 - defaults: min_pts = 10 x features, eps = sqrt(features);
 - per-point bookkeeping in one int16 word: "the first three bits indicate if
   the data item has been visited and the density reachability.  The other
@@ -37,7 +37,11 @@ import numpy as np
 
 from repro.core.cancellation import CancellationToken
 from repro.core.meter import StepMeter
-from repro.kernels.neighbor.ops import epsilon_degree, expand_frontier
+from repro.kernels.neighbor.ops import (
+    epsilon_degree,
+    expand_padded,
+    pad_points,
+)
 from repro.kernels.neighbor.ref import epsilon_degree_ref, expand_frontier_ref
 
 # --- the paper's int16 state word ------------------------------------------
@@ -131,11 +135,21 @@ def _degree(x, cfg: DBSCANConfig):
     return epsilon_degree_ref(x, cfg.eps)
 
 
-def _expand(x, frontier, cfg: DBSCANConfig):
+def _points(x, cfg: DBSCANConfig):
+    """The points as each expansion reads them: padded once for the
+    kernel, as they are for the reference."""
     if cfg.use_kernel:
-        return expand_frontier(x, frontier, cfg.eps, block_i=cfg.block_i,
-                               block_j=cfg.block_j)
-    return expand_frontier_ref(x, frontier, cfg.eps)
+        return pad_points(x, block_i=cfg.block_i, block_j=cfg.block_j)
+    return x
+
+
+def _expand_step(xp, frontier, cfg: DBSCANConfig):
+    """One expansion: the points within eps of the frontier, of the
+    points as :func:`_points` gives them."""
+    if cfg.use_kernel:
+        return expand_padded(xp, frontier, cfg.eps, block_i=cfg.block_i,
+                             block_j=cfg.block_j)
+    return expand_frontier_ref(xp, frontier, cfg.eps)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -146,11 +160,65 @@ def _degree_step(x, cfg: DBSCANConfig):
     return _degree(x, cfg)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _expand_step(x, frontier, cfg: DBSCANConfig):
-    """Module-level jitted expansion: cached across host-loop invocations, so
-    a service running many same-shaped requests compiles once per shape."""
-    return _expand(x, frontier, cfg)
+# the breadth-first search's loop state: per-point labels, visited and
+# member flags, the frontier of the cluster being expanded, that cluster's
+# id and the expansions so far
+Carry = Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array,
+              jax.Array]
+
+
+def _bfs(xp, core, carry: Carry, cfg: DBSCANConfig, expand,
+         stop=None, max_cid=MAX_CLUSTER_ID) -> Carry:
+    """The device loop of both solvers: expand the frontier while it
+    holds points, else seed the next cluster at the lowest-index
+    unvisited core point.  Ends when no core point is left unvisited,
+    when seeding would pass ``max_cid``, or when the expansions reach
+    ``stop`` (a traced count; ``None`` runs to the end)."""
+    n = core.shape[0]
+
+    def cond(c):
+        _, visited, _, frontier, cid, nexp = c
+        go = frontier.any() | ((core & ~visited).any() & (cid < max_cid))
+        return go if stop is None else go & (nexp < stop)
+
+    def grow(c):
+        labels, visited, member, frontier, cid, nexp = c
+        # unclaimed (noise or unvisited) points join this cluster; only
+        # newly-claimed core points keep expanding
+        new = expand(xp, frontier, cfg) & (labels == 0)
+        return (jnp.where(new, cid, labels), visited | new, member | new,
+                new & core, cid, nexp + 1)
+
+    def seed(c):
+        labels, visited, member, _, cid, nexp = c
+        frontier = jnp.zeros((n,), bool).at[
+            jnp.argmax(core & ~visited)].set(True)
+        return labels, visited, member, frontier, cid + 1, nexp
+
+    def body(c):
+        _, _, _, frontier, _, _ = c
+        return jax.lax.cond(frontier.any(), grow, seed, c)
+
+    return jax.lax.while_loop(cond, body, carry)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "expand"))
+def _expand_steps(x, core, carry: Carry, stop, cfg: DBSCANConfig,
+                  expand=_expand_step):
+    """One device program of the host-driven run: the search from
+    ``carry`` until its expansions reach ``stop`` or the run ends.
+    Returns the carry and whether the run is finished.  Module-level, so
+    a service running many same-shaped requests compiles once per shape;
+    ``stop`` is traced, so every stretch of a run shares that program."""
+    carry = _bfs(_points(x, cfg), core, carry, cfg, expand, stop=stop)
+    _, visited, _, frontier, _, _ = carry
+    return carry, ~(frontier.any() | (core & ~visited).any())
+
+
+def _start(n: int) -> Carry:
+    return (jnp.zeros((n,), jnp.int32), jnp.zeros((n,), bool),
+            jnp.zeros((n,), bool), jnp.zeros((n,), bool), jnp.int32(0),
+            jnp.int32(0))
 
 
 # --- fully jitted solver -----------------------------------------------------
@@ -158,49 +226,12 @@ def _expand_step(x, frontier, cfg: DBSCANConfig):
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def fit(x: jnp.ndarray, cfg: DBSCANConfig) -> DBSCANResult:
-    """Fully jitted DBSCAN (nested lax.while_loop)."""
-    n = x.shape[0]
-    deg = _degree(x, cfg)
-    core = deg >= cfg.min_pts
-
-    def expand_cluster(labels, visited, cid):
-        """BFS-expand the cluster seeded at the first unvisited core pt."""
-        seed = jnp.argmax(core & ~visited)
-        frontier = jnp.zeros((n,), bool).at[seed].set(True)
-
-        def cond(s):
-            frontier, _, _, _ = s
-            return frontier.any()
-
-        def body(s):
-            frontier, labels, visited, nexp = s
-            reached = _expand(x, frontier, cfg)
-            # unclaimed (noise or unvisited) points join this cluster
-            new = reached & (labels == 0)
-            labels = jnp.where(new, cid, labels)
-            visited = visited | new
-            # only newly-claimed core points keep expanding
-            return new & core, labels, visited, nexp + 1
-
-        frontier, labels, visited, nexp = jax.lax.while_loop(
-            cond, body, (frontier, labels, visited, jnp.int32(0))
-        )
-        return labels, visited, nexp
-
-    def outer_cond(s):
-        _, visited, _, _ = s
-        return (core & ~visited).any()
-
-    def outer_body(s):
-        labels, visited, cid, nexp = s
-        labels, visited, e = expand_cluster(labels, visited, cid + 1)
-        return labels, visited, cid + 1, nexp + e
-
-    labels0 = jnp.zeros((n,), jnp.int32)
-    visited0 = jnp.zeros((n,), bool)
-    labels, visited, cid, nexp = jax.lax.while_loop(
-        outer_cond, outer_body, (labels0, visited0, jnp.int32(0), jnp.int32(0))
-    )
+    """Fully jitted DBSCAN: the whole search in one device loop."""
+    core = _degree(x, cfg) >= cfg.min_pts
+    # the answer's int16 labels bound the cluster ids, not the state word
+    labels, _, _, _, cid, nexp = _bfs(
+        _points(x, cfg), core, _start(x.shape[0]), cfg, _expand_step,
+        max_cid=np.iinfo(np.int16).max)
     return DBSCANResult(
         labels=labels.astype(jnp.int16),
         core_mask=core,
@@ -210,10 +241,6 @@ def fit(x: jnp.ndarray, cfg: DBSCANConfig) -> DBSCANResult:
 
 
 # --- host-driven, cancellable + resumable solver ----------------------------
-
-
-def _host_any(a) -> bool:
-    return bool(a.any())
 
 
 @dataclasses.dataclass
@@ -262,47 +289,50 @@ def fit_resumable(
     state_interval: int = 8,
     meter: Optional[StepMeter] = None,
 ) -> Tuple[DBSCANResult, Optional[DBSCANRunState]]:
-    """Host loop; the abort flag is polled between kernel executions, exactly
-    as in the paper.  State is carried in the paper's packed int16 word.
+    """Host loop over device programs; the abort flag is polled before
+    each, as the paper polls it between kernel executions.  State is
+    carried in the paper's packed int16 word.
+
+    A program runs the search up to the next multiple of ``state_interval`` expansions, or to
+    the end; one blocking read of (finished, cluster id, expansions)
+    follows it, then ``on_progress(cid, nexp)`` and, where the run goes
+    on, ``on_state``.  So cancellation is seen within ``state_interval``
+    expansions.
 
     ``state`` resumes a previously interrupted run mid-BFS; on cancellation
     the returned second element is the snapshot to resume from (``None`` on
     normal completion).  ``on_state`` is invoked every ``state_interval``
-    expansions — the service's periodic-checkpoint hook — with a function
-    that reads the snapshot back from the device when called (during the
-    hook), so the caller's checkpoint covers the read-back that feeds it.
-    ``valid_mask`` marks real rows in a padded array: masked-out rows can
-    never be core points (with min_pts=1 an isolated pad row would
-    otherwise seed a phantom singleton cluster).  ``meter`` counts the step
-    programs (the degree pass and each expansion) and every blocking
-    device-to-host read the loop makes.
+    expansions of an unfinished run — the service's periodic-checkpoint
+    hook — with a function that reads the snapshot back from the device
+    when called (during the hook), so the caller's checkpoint covers the
+    read-back that feeds it.  ``valid_mask`` marks real rows in a padded
+    array: masked-out rows can never be core points (with min_pts=1 an
+    isolated pad row would otherwise seed a phantom singleton cluster).
+    ``meter`` counts the device programs (the degree pass and each
+    stretch), the steps they ran (the degree pass and each expansion) and
+    every blocking device-to-host read the loop makes.
     """
     meter = meter if meter is not None else StepMeter()
     n = x.shape[0]
     deg = _degree_step(x, cfg)       # kernel launch 1 (main loop kernel)
-    meter.steps += 1
+    meter.program()
     core = deg >= cfg.min_pts
     if valid_mask is not None:
         core = core & valid_mask
 
     if state is not None:
         labels, visited, member, _ = unpack_state(jnp.asarray(state.packed))
-        frontier = jnp.asarray(state.frontier)
         cid = int(state.cid)
         nexp = int(state.nexp)
+        carry = (labels, visited, member, jnp.asarray(state.frontier),
+                 jnp.int32(cid), jnp.int32(nexp))
     else:
-        labels = jnp.zeros((n,), jnp.int32)
-        visited = jnp.zeros((n,), bool)
-        member = jnp.zeros((n,), bool)
-        frontier = jnp.zeros((n,), bool)
-        cid = 0
-        nexp = 0
+        carry = _start(n)
+        cid = nexp = 0
     cancelled = False
 
-    def _poll() -> bool:
-        return token is not None and token.cancelled()
-
     def _snapshot() -> DBSCANRunState:
+        labels, visited, member, frontier, _, _ = carry
         return DBSCANRunState(
             packed=np.asarray(pack_state(labels, visited, member, core)),
             frontier=np.asarray(frontier),
@@ -315,40 +345,34 @@ def fit_resumable(
         return meter.read(_snapshot, reads=3)
 
     while True:
-        # inner: expand the in-flight cluster's frontier to exhaustion
-        while meter.read(_host_any, frontier):
-            if _poll():
-                cancelled = True
-                break
-            reached = _expand_step(x, frontier, cfg)  # expansion kernel launch
-            meter.steps += 1
-            nexp += 1
-            new = reached & (labels == 0)
-            labels = jnp.where(new, cid, labels)
-            visited = visited | new
-            member = member | new
-            frontier = new & core
-            if on_progress is not None:
-                on_progress(cid, nexp)
-            if on_state is not None and nexp % state_interval == 0:
-                on_state(_read_snapshot)
-        if cancelled:
-            break
-        if _poll():
+        if token is not None and token.cancelled():
             cancelled = True
             break
-        # outer: seed the next cluster at the lowest-index unvisited core pt
-        todo = meter.read(np.asarray, core & ~visited)
-        if not todo.any():
+        stop = (nexp // state_interval + 1) * state_interval
+        # the expansion is looked up per call, so a replaced
+        # `_expand_step` is traced afresh
+        carry, done = _expand_steps(x, core, carry, jnp.int32(stop),
+                                    cfg=cfg, expand=_expand_step)
+        *_, cid_now, nexp_now = carry
+        done, cid_now, nexp_now = meter.read(
+            jax.device_get, (done, cid_now, nexp_now))
+        meter.program(steps=int(nexp_now) - nexp)
+        cid, nexp = int(cid_now), int(nexp_now)
+        if on_progress is not None:
+            on_progress(cid, nexp)
+        if done:
             break
-        cid += 1
-        if cid > MAX_CLUSTER_ID:
+        if nexp < stop:
+            # stopped short: the next cluster's id would not fit the word
             raise ValueError(
                 f"dataset produced more than {MAX_CLUSTER_ID} clusters — the "
-                f"paper's int16 state word cannot represent cluster id {cid}"
+                f"paper's int16 state word cannot represent cluster id "
+                f"{cid + 1}"
             )
-        frontier = jnp.zeros((n,), bool).at[int(np.argmax(todo))].set(True)
+        if on_state is not None:     # nexp == stop, a multiple of the interval
+            on_state(_read_snapshot)
 
+    labels, visited, member, _, _, _ = carry
     # pack_state reads the largest cluster id back to bound it
     packed = meter.read(pack_state, labels, visited, member, core)
     result = DBSCANResult(

@@ -227,8 +227,9 @@ def ring_expand(
 # Resumable sharded fits — the serving layer's oversized-request path
 # ---------------------------------------------------------------------------
 #
-# Both loops mirror their single-device twins (`kmeans.fit_cancellable`,
-# `dbscan.fit_resumable`): the abort flag is polled between collective
+# Both loops keep the contract of their single-device twins
+# (`kmeans.fit_cancellable`, `dbscan.fit_resumable`, which runs stretches
+# of expansions per program): the abort flag is polled between collective
 # launches, and the state reported through ``on_state`` is *gathered to the
 # host* and device-count independent — K-Means state is the replicated
 # (k, d) centroid matrix + iteration counter, DBSCAN state is the paper's
@@ -286,7 +287,7 @@ def sharded_kmeans_fit_resumable(
             cancelled = True
             break
         assign, c, shift, inertia = step(xs, c, ms)
-        meter.steps += 1
+        meter.program()
         stepped = True
         it += 1
         if on_state is not None and it % state_interval == 0:
@@ -300,7 +301,7 @@ def sharded_kmeans_fit_resumable(
         # of the *incoming* centroids (computed before the update), which
         # we keep — without it the result would be all-zero labels.
         assign, _, _, inertia = step(xs, c, ms)
-        meter.steps += 1
+        meter.program()
     result = KMeansResult(
         centroids=c,
         labels=jnp.asarray(assign).astype(jnp.int16),
@@ -342,7 +343,7 @@ def sharded_dbscan_fit_resumable(
     xs = jax.device_put(jnp.asarray(x_pad, jnp.float32), x_sharding)
 
     deg = meter.read(np.asarray, degree_fn(xs))   # ring launch 1 (degree)
-    meter.steps += 1
+    meter.program()
     core = deg >= cfg.min_pts
     if valid_mask is not None:
         core = core & np.asarray(valid_mask)
@@ -384,7 +385,7 @@ def sharded_dbscan_fit_resumable(
                 break
             fs = jax.device_put(jnp.asarray(frontier), f_sharding)
             reached = meter.read(np.asarray, expand_fn(xs, fs))  # ring launch
-            meter.steps += 1
+            meter.program()
             nexp += 1
             new = reached & (labels == 0)
             labels = np.where(new, cid, labels)

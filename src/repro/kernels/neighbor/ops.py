@@ -30,14 +30,22 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _pad_points(x: jnp.ndarray, block: int):
+def _blocks(n: int, block_i: Optional[int], block_j: Optional[int]):
+    return (block_i or min(DEFAULT_BLOCK_I, _round_up(n, 8)),
+            block_j or min(DEFAULT_BLOCK_J, _round_up(n, 8)))
+
+
+def pad_points(x: jnp.ndarray, *, block_i: Optional[int] = None,
+               block_j: Optional[int] = None) -> jnp.ndarray:
+    """``x`` as the neighbourhood kernels read it: rows padded to a
+    multiple of both blocks, features to 128 lanes."""
     n, d = x.shape
-    n_pad = _round_up(n, block)
+    bi, bj = _blocks(n, block_i, block_j)
+    n_pad = _round_up(n, max(bi, bj))
     d_pad = _round_up(d, 128)
     xp = jnp.zeros((n_pad, d_pad), x.dtype).at[:, 0].set(_PAD_COORD)
     xp = xp.at[:n, :d].set(x)
-    xp = xp.at[:n, d:].set(0.0)
-    return xp, n_pad, d_pad
+    return xp.at[:n, d:].set(0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_i", "block_j", "interpret"))
@@ -53,13 +61,36 @@ def epsilon_degree(
     if interpret is None:
         interpret = pallas_interpret()
     n, _ = x.shape
-    bi = block_i or min(DEFAULT_BLOCK_I, _round_up(n, 8))
-    bj = block_j or min(DEFAULT_BLOCK_J, _round_up(n, 8))
-    b = max(bi, bj)
-    xp, _, _ = _pad_points(x, b)
+    bi, bj = _blocks(n, block_i, block_j)
+    xp = pad_points(x, block_i=bi, block_j=bj)
     eps2 = jnp.asarray(eps, jnp.float32) ** 2
     deg = degree_kernel(xp, eps2, block_i=bi, block_j=bj, interpret=interpret)
     return deg[:n, 0]
+
+
+def expand_padded(
+    xp: jnp.ndarray,
+    frontier: jnp.ndarray,
+    eps: jnp.ndarray | float,
+    *,
+    block_i: Optional[int] = None,
+    block_j: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """:func:`expand_frontier` on points already padded by
+    :func:`pad_points` (same blocks): a loop that expands many frontiers
+    of one point set pads it once."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    n = frontier.shape[0]
+    bi, bj = _blocks(n, block_i, block_j)
+    fp = jnp.zeros((xp.shape[0], 1), jnp.float32).at[:n, 0].set(
+        frontier.astype(jnp.float32)
+    )
+    eps2 = jnp.asarray(eps, jnp.float32) ** 2
+    counts = expand_kernel(xp, fp, eps2, block_i=bi, block_j=bj,
+                           interpret=interpret)
+    return counts[:n, 0] > 0.5
 
 
 @functools.partial(jax.jit, static_argnames=("block_i", "block_j", "interpret"))
@@ -73,17 +104,6 @@ def expand_frontier(
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Bool (n,): within eps of some frontier point (the expansion kernel)."""
-    if interpret is None:
-        interpret = pallas_interpret()
-    n, _ = x.shape
-    bi = block_i or min(DEFAULT_BLOCK_I, _round_up(n, 8))
-    bj = block_j or min(DEFAULT_BLOCK_J, _round_up(n, 8))
-    b = max(bi, bj)
-    xp, n_pad, _ = _pad_points(x, b)
-    fp = jnp.zeros((n_pad, 1), jnp.float32).at[:n, 0].set(
-        frontier.astype(jnp.float32)
-    )
-    eps2 = jnp.asarray(eps, jnp.float32) ** 2
-    counts = expand_kernel(xp, fp, eps2, block_i=bi, block_j=bj,
-                           interpret=interpret)
-    return counts[:n, 0] > 0.5
+    xp = pad_points(x, block_i=block_i, block_j=block_j)
+    return expand_padded(xp, frontier, eps, block_i=block_i,
+                         block_j=block_j, interpret=interpret)

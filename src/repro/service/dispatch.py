@@ -182,11 +182,11 @@ class ItemProbe:
     into; :meth:`span` wraps other work of an item (``host_compute``, a
     checkpoint).  With a ``tracer``, each becomes a span under the item's
     trace (``traces[index]``), and a ``steps`` span carries its meter's
-    ``steps``/``syncs``/``sync_s`` as attrs.  A span begun on a thread
-    with none of its trace open is filed under ``parents[trace]`` (the
-    batch's execute span).  The seconds blocked in reads, summed over the
-    batch, are :attr:`sync_s`, with or without a tracer: the batch's
-    measured device time.
+    ``steps``/``programs``/``syncs``/``sync_s`` as attrs.  A span begun
+    on a thread with none of its trace open is filed under
+    ``parents[trace]`` (the batch's execute span).  The seconds blocked
+    in reads, summed over the batch, are :attr:`sync_s`, with or without
+    a tracer: the batch's measured device time.
     """
 
     def __init__(self, tracer=None, traces: Sequence[str] = (),
@@ -389,7 +389,7 @@ class JaxParadigm(Paradigm):
             # incoming centroids (computed before the update) rather than
             # completing with all-zero labels
             assign, _, _, inertia = step(slot["x"], slot["c"], slot["mask"])
-            meter.steps += 1
+            meter.program()
             slot["assign"], slot["inertia"] = assign, inertia
         return meter.read(np.asarray, slot["assign"], np.int16), {
             "inertia": meter.read(float, slot["inertia"]),
@@ -413,7 +413,7 @@ class JaxParadigm(Paradigm):
                         mid_state=self._kmeans_mid(slot, meter))
                 assign, c, shift, inertia = step(
                     slot["x"], slot["c"], slot["mask"])
-                meter.steps += 1
+                meter.program()
                 slot["assign"], slot["c"], slot["inertia"] = assign, c, inertia
                 slot["stepped"] = True
                 slot["it"] += 1
@@ -468,7 +468,7 @@ class JaxParadigm(Paradigm):
                 while slot["it"] < cfg.max_iters and quantum < state_interval:
                     assign, c, shift, inertia = step(
                         slot["x"], slot["c"], slot["mask"])
-                    meter.steps += 1
+                    meter.program()
                     slot["assign"], slot["c"] = assign, c
                     slot["inertia"] = inertia
                     slot["stepped"] = True

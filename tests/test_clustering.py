@@ -68,6 +68,34 @@ def test_dbscan_matches_oracle(features, clusters, size):
     assert (np.asarray(res_host.labels) == oracle).all()
 
 
+@pytest.mark.parametrize("state_interval", [1, 3, 8, 10**6])
+def test_dbscan_resumable_matches_oracle_at_every_interval(state_interval):
+    """One device program per stretch of ``state_interval`` expansions
+    gives the oracle's labels and the jitted solver's expansions; every
+    snapshot falls on a multiple of the interval and resumes to the same
+    answer."""
+    x, _, _ = make_blobs(jax.random.PRNGKey(62), ClusterSpec(2, 6, 128))
+    cfg = dbscan.DBSCANConfig(eps=float(np.sqrt(2)), min_pts=20,
+                              use_kernel=False)
+    oracle = dbscan.fit_oracle(np.asarray(x), cfg)
+    expansions = int(dbscan.fit(x, cfg).expansions)
+    snapshots = []
+    res, _ = dbscan.fit_resumable(
+        x, cfg, state_interval=state_interval,
+        on_state=lambda read: snapshots.append(read()))
+    assert (np.asarray(res.labels) == oracle).all()
+    assert int(res.expansions) == expansions
+    assert [s.nexp for s in snapshots] == list(
+        range(state_interval, expansions, state_interval))
+    for snap in snapshots:
+        resumed, _ = dbscan.fit_resumable(
+            x, cfg, state=dbscan.DBSCANRunState.from_tree(snap.as_tree()),
+            state_interval=state_interval)
+        assert (np.asarray(resumed.labels) == oracle).all()
+        assert int(resumed.expansions) == expansions
+        assert int(resumed.n_clusters) == int(res.n_clusters)
+
+
 def test_dbscan_kernel_vs_ref_path():
     key = jax.random.PRNGKey(11)
     x, _, _ = make_blobs(key, ClusterSpec(2, 4, 128))

@@ -226,7 +226,8 @@ def test_pack_state_overflow_raises():
         dbscan.pack_state(bad, flags, flags, flags)
 
 
-def test_dbscan_resumable_continues_exactly():
+@pytest.mark.parametrize("state_interval", [1, 8])
+def test_dbscan_resumable_continues_exactly(state_interval):
     x = jnp.asarray(blob(5, clusters=8, points=64))
     cfg = dbscan.DBSCANConfig(eps=DB_CFG.eps, min_pts=DB_CFG.min_pts,
                               use_kernel=False)
@@ -236,12 +237,16 @@ def test_dbscan_resumable_continues_exactly():
 
     def progress(cid, nexp):
         seen.append(nexp)
-        if nexp == 3:
+        if nexp >= 3:
             token.cancel()
 
-    partial, state = dbscan.fit_resumable(x, cfg, token, on_progress=progress)
+    partial, state = dbscan.fit_resumable(x, cfg, token, on_progress=progress,
+                                          state_interval=state_interval)
     assert partial.cancelled and state is not None
-    assert state.nexp == 3
+    # progress comes once per device program, at the end of each stretch
+    # of state_interval expansions: cancellation is seen there
+    assert state.nexp == (3 if state_interval == 1 else 8)
+    assert seen == list(range(state_interval, state.nexp + 1, state_interval))
     # round-trip through the checkpointable tree form
     state = dbscan.DBSCANRunState.from_tree(state.as_tree())
     resumed, state2 = dbscan.fit_resumable(x, cfg, state=state)

@@ -23,6 +23,7 @@ from jax.sharding import (
     SingleDeviceSharding,
 )
 
+from repro.core import dbscan
 from repro.core.distributed import make_ring_degree, make_ring_expand
 from repro.kernels.distance.distance import assign_clusters_kernel
 from repro.kernels.distance.fused import fused_step_kernel
@@ -87,6 +88,24 @@ def test_expand_kernel_compiles_for_v5e(one_chip):
     eps2 = _aval((), jnp.float32, one_chip)
     _assert_mosaic(expand_kernel.lower(
         x, front, eps2, interpret=False).compile())
+
+
+def test_dbscan_expansion_loop_compiles_for_v5e(one_chip, monkeypatch):
+    """The Pallas expansion kernel survives inside the host loop's
+    while-loop program."""
+    from repro.kernels.neighbor import ops
+
+    # the program asks the default backend, the CPU here, whether to
+    # interpret its kernels; the described chip compiles them to Mosaic
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    x = _aval((N, FEATURES), jnp.float32, one_chip)
+    flags = _aval((N,), jnp.bool_, one_chip)
+    count = _aval((), jnp.int32, one_chip)
+    carry = (_aval((N,), jnp.int32, one_chip), flags, flags, flags, count,
+             count)
+    cfg = dbscan.DBSCANConfig(eps=EPS, min_pts=10 * FEATURES)
+    _assert_mosaic(dbscan._expand_steps.lower(
+        x, flags, carry, count, cfg=cfg).compile())
 
 
 @pytest.mark.parametrize("which", ["expand", "degree"])

@@ -152,11 +152,16 @@ def test_dbscan_item_counts_its_steps_and_syncs():
     e, c = answer["expansions"], answer["n_clusters"]
     assert e >= si and c >= 2
     assert steps.attrs["steps"] == e + 1          # the degree pass + each
-    # per expansion one frontier test; per cluster one more (its last) and
-    # one read of the unvisited core points, plus one of each at the end;
-    # three reads per snapshot; pack_state's bound; labels and expansions
-    assert steps.attrs["syncs"] == e + 2 * c + 3 * (e // si) + 5
-    assert len(reads) == e // si
+    # the degree pass, then one program per stretch of si expansions, the
+    # last one ending where the run does
+    chunks = -(-e // si)
+    assert steps.attrs["programs"] == chunks + 1
+    # one read per program of (finished, cluster id, expansions); a
+    # snapshot of three reads at each multiple of si short of the end;
+    # pack_state's bound; labels and expansions
+    snapshots = chunks - 1
+    assert steps.attrs["syncs"] == chunks + 3 * snapshots + 3
+    assert len(reads) == snapshots
     assert steps.attrs["lane"] == EXECUTOR_JAX_REF
     assert steps.attrs["algo"] == "dbscan" and steps.attrs["d"] == 2
     assert 0.0 < steps.attrs["sync_s"] <= steps.dur_s
@@ -175,6 +180,7 @@ def test_kmeans_item_counts_its_steps_and_syncs(continuous):
     it = answer["iterations"]
     assert it > 2 * si
     assert sum(s.attrs["steps"] for s in steps) == it
+    assert all(s.attrs["programs"] == s.attrs["steps"] for s in steps)
     syncs = sum(s.attrs["syncs"] for s in steps)
     if continuous:
         # one span per quantum of si iterations: a shift read per
