@@ -27,6 +27,13 @@ float32 inside the unit, and the result of every array operation (norms,
 cross term, distances, centroid sums and means) is rounded to bfloat16.  It
 is the control: the nearest precision below the float32 that the
 configuration states.
+
+Memory: DBSCAN holds its eps-adjacency as one bit per pair (n²/8 bytes) and
+nothing else of size n².  The distances are filled in blocks of rows whose
+size follows from n and the worker count, so that the workers' temporaries
+together stay under ``WORK_BYTES`` however many CPUs the host reports; every
+distance is computed in place, by the same float32 operations in the same
+order whatever the block, so the answers do not depend on the block size.
 """
 
 from __future__ import annotations
@@ -36,8 +43,17 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-BLOCK_ROWS = 512
 PRECISIONS = ("float32", "bf16")
+# The bound on the DBSCAN workers' temporaries together.  The reference
+# runs in the process that holds the service and every request's points,
+# after the window; at n = 65,536 the adjacency takes 512 MiB, and 1 GiB
+# beside it keeps the whole reference under 2 GiB on any host, while
+# leaving blocks of hundreds of rows at the sizes of the one-chip cells.
+WORK_BYTES = 1 << 30
+# Bytes per pair of a block that a worker holds at once: the float32 sums
+# and one float32 temporary (the bfloat16 path: its 2-byte rounding
+# scratch; then the 1-byte mask beside the sums).
+PAIR_BYTES = 8
 
 
 def _bf16(a: np.ndarray) -> np.ndarray:
@@ -61,23 +77,49 @@ def sample_init(seed: int, n: int, k: int) -> np.ndarray:
 
 
 def _sq_dists_f32(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """(len(a), len(b)) squared distances by direct differences, float32."""
+    """(len(a), len(b)) squared distances by direct differences, float32,
+    feature by feature; holds the result and one temporary of its size."""
     out = np.zeros((a.shape[0], b.shape[0]), np.float32)
+    diff = np.empty_like(out)
     for j in range(a.shape[1]):
-        diff = a[:, j, None] - b[None, :, j]
-        out += diff * diff
+        np.subtract(a[:, j, None], b[None, :, j], out=diff)
+        np.multiply(diff, diff, out=diff)
+        out += diff
     return out
 
 
 def _sq_dists_bf16(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """The same on bfloat16 arrays, in the matrix-unit form."""
+    """The same on bfloat16 arrays, in the matrix-unit form.  A product of
+    two bfloat16 values is exact in float32; the cross term sums them in
+    float32 in feature order, which is the order of a matrix product of
+    more than one row, so the result does not depend on the block's shape
+    (a one-row product may sum in another order)."""
+    import ml_dtypes
+
     a = _bf16(a)
     b = _bf16(b)
     na = _bf16(np.sum(a * a, axis=1, dtype=np.float32))
     nb = _bf16(np.sum(b * b, axis=1, dtype=np.float32))
-    cross = _bf16(a @ b.T)
-    return _bf16(_bf16(na[:, None] - _bf16(np.float32(2.0) * cross))
-                 + nb[None, :])
+    out = np.zeros((a.shape[0], b.shape[0]), np.float32)
+    prod = np.empty_like(out)
+    for j in range(a.shape[1]):
+        np.multiply(a[:, j, None], b[None, :, j], out=prod)
+        out += prod
+    del prod
+    scratch = np.empty(out.shape, ml_dtypes.bfloat16)
+
+    def round_out() -> None:
+        np.copyto(scratch, out, casting="unsafe")
+        np.copyto(out, scratch, casting="unsafe")
+
+    round_out()                                  # cross
+    out *= np.float32(2.0)
+    round_out()
+    np.subtract(na[:, None], out, out=out)
+    round_out()
+    out += nb[None, :]
+    round_out()
+    return out
 
 
 def sq_dists(a: np.ndarray, b: np.ndarray, precision: str) -> np.ndarray:
@@ -126,8 +168,16 @@ def kmeans(x: np.ndarray, init_idx: np.ndarray, *, tol: float,
             "converged": converged}
 
 
-def _blocks(n: int, size: int = BLOCK_ROWS):
+def _blocks(n: int, size: int):
     return [(s, min(n, s + size)) for s in range(0, n, size)]
+
+
+def _plan(n: int, threads: int):
+    """(workers, rows per block) for filling n columns: together under
+    ``WORK_BYTES``."""
+    workers = max(1, min(threads or os.cpu_count() or 1,
+                         WORK_BYTES // (PAIR_BYTES * n)))
+    return workers, max(1, WORK_BYTES // (workers * PAIR_BYTES * n))
 
 
 def dbscan(x: np.ndarray, *, eps: float, min_pts: int,
@@ -149,8 +199,11 @@ def dbscan(x: np.ndarray, *, eps: float, min_pts: int,
         deg[s:e] = near.sum(axis=1)
         adj[s:e] = np.packbits(near, axis=1)
 
-    with ThreadPoolExecutor(max_workers=threads or os.cpu_count()) as pool:
-        list(pool.map(fill, _blocks(n)))
+    workers, rows = _plan(n, threads)
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        list(pool.map(fill, _blocks(n, rows)))
+    # frontier rows are OR-ed in chunks, under the same bound
+    chunk = max(1, WORK_BYTES // adj.shape[1])
     core = deg >= min_pts
     labels = np.zeros(n, np.int64)
     cid = 0
@@ -162,8 +215,11 @@ def dbscan(x: np.ndarray, *, eps: float, min_pts: int,
         cid += 1
         frontier = todo[:1]
         while frontier.size:
-            rows = np.bitwise_or.reduce(adj[frontier], axis=0)
-            reached = np.unpackbits(rows, count=n).astype(bool)
+            near = np.zeros(adj.shape[1], np.uint8)
+            for s in range(0, frontier.size, chunk):
+                near |= np.bitwise_or.reduce(adj[frontier[s:s + chunk]],
+                                             axis=0)
+            reached = np.unpackbits(near, count=n).astype(bool)
             new = reached & (labels == 0)
             labels[new] = cid
             frontier = np.flatnonzero(new & core)

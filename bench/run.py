@@ -16,7 +16,10 @@ chips, the run
 5. reads the device's peak memory, stops the service, and compares a
    sample of the answers with the plain reference (bench/reference.py,
    bench/compare.py);
-6. prints, on standard output, report lines starting with ``#`` and, last,
+6. prints, on standard output, report lines starting with ``#`` (among
+   them the process's peak resident memory and the host's CPU count at
+   the end of each phase: data, warm-up, window, the trace's reading
+   with ``--trace 1``, reference) and, last,
    one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``
    (the cell's end-to-end metrics with ``--trace 0``; its per-layer
    metrics, read from a profiler trace of the window, with ``--trace 1``),
@@ -43,6 +46,7 @@ import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -190,6 +194,15 @@ def reference_numbers(rec, precision: str = "float32") -> Dict[str, float]:
                                   expansions, ref)
 
 
+def host_memory_line(phase: str) -> str:
+    """The report line for the end of ``phase``: the process's peak
+    resident memory so far (``ru_maxrss``, KiB on Linux) in bytes, and the
+    host's CPU count, which sizes the reference's thread pool."""
+    peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    return (f"host memory: peak_rss_bytes {peak} after {phase} "
+            f"(cpu_count {os.cpu_count()})")
+
+
 def _device_report(devices) -> dict:
     peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
              for d in devices]
@@ -251,6 +264,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
     loadgen.make_data(config, seed, reqs)
     say(f"set-up: {len(reqs)} requests made in "
         f"{time.perf_counter() - t_gen:.3f} s")
+    say(host_memory_line("data"))
 
     workdir = tempfile.mkdtemp(prefix="bench-service-")
     service = ClusteringService(workdir)
@@ -264,6 +278,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
             f"{len(compile_times)} programs built so far, "
             f"{cache_events.get('hits', 0)} read from the compilation "
             f"cache, {cache_events.get('misses', 0)} compiled")
+        say(host_memory_line("warm-up"))
         if trace:
             tmp_trace = tempfile.mkdtemp(prefix="bench-trace-")
             opts = jax.profiler.ProfileOptions()
@@ -292,6 +307,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
     finally:
         service.stop()
         shutil.rmtree(workdir, ignore_errors=True)
+    say(host_memory_line("window"))
 
     built = [name for t, name in compile_times if window.t0 <= t <= t_end]
     compiles = len(built)
@@ -303,6 +319,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
         shutil.rmtree(tmp_trace, ignore_errors=True)
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
+        say(host_memory_line("trace"))
 
     lanes: Dict[str, int] = {}
     for r in window.records:
@@ -338,6 +355,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, seed: int,
     correct, checks = compare.judge(numbers, config["limits"])
     say(f"reference: {len(sample)} answers re-computed in "
         f"{time.perf_counter() - t_ref:.3f} s")
+    say(host_memory_line("reference"))
 
     result = {"correct": bool(correct), "attempted": len(window.records),
               "failed": shed + errors + window.unanswered,

@@ -8,7 +8,9 @@ the Pallas lane's kernels would run in interpret mode, too slowly for a
 test.
 """
 
+import io
 import os
+import re
 
 import numpy as np
 import pytest
@@ -119,8 +121,12 @@ def test_fault_makes_run_not_correct(algo, fault, monkeypatch):
     if FAULTS[algo][fault] is not None:
         FAULTS[algo][fault](monkeypatch)
     cell, config, traffic = _cell(algo)
+    out = io.StringIO()
     result = run.run_cell(cell, config, traffic, 2**32 + 17, 2.0, False, [],
-                          require_accelerator=False,
-                          out=open(os.devnull, "w"))
+                          require_accelerator=False, out=out)
     assert result["attempted"] > 0
     assert result["correct"] is (fault == "none"), result["checks"]
+    # the host's memory at the end of each phase, in order
+    phases = re.findall(r"^# host memory: peak_rss_bytes \d+ after (\S+)",
+                        out.getvalue(), re.M)
+    assert phases == ["data", "warm-up", "window", "reference"]
